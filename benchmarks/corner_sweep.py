@@ -49,6 +49,9 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
 
     import jax
+    from repro import compile_cache
+
+    compile_cache.enable()
     import jax.numpy as jnp
     from repro.api import DesignTable, design_space, explore
     from repro.core import corners, gainsight

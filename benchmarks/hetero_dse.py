@@ -51,6 +51,9 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
 
     import jax
+    from repro import compile_cache
+
+    compile_cache.enable()
     from repro.api import DesignTable, design_space
     from repro.core import gainsight
     from repro.hetero import ComposePolicy, compose

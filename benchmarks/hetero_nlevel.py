@@ -53,6 +53,9 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
 
     import jax
+    from repro import compile_cache
+
+    compile_cache.enable()
     from repro.api import DesignTable, design_space
     from repro.core.gainsight import nlevel_task
     from repro.hetero import ComposePolicy, compose

@@ -102,6 +102,8 @@ def main(argv=None) -> None:
                          "(default: informational, exit 0)")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
+    compile_cache.enable()
     if args.quick or args.suite or args.compare is not None:
         diff = run_suite(args.quick, Path(args.out_dir),
                          compare_dir=args.compare,
@@ -205,29 +207,27 @@ def _csv_main() -> None:
           f"PPA+retention under one vmapped corner grid\"")
 
     # per-arch heterogeneous-memory DSE (the paper's technique on our archs)
-    try:
-        from benchmarks.arch_dse import arch_dse_table
-        (rows, derived), us = _timed(arch_dse_table)
+    # and the roofline table: both read dry-run records, which a fresh
+    # checkout does not have; any other failure raises
+    from benchmarks.arch_dse import arch_dse_table
+    (rows, derived), us = _timed(arch_dse_table)
+    if rows:
         print(f"arch_dse,{us:.0f},\"{derived}\"")
-    except Exception as e:
-        print(f"arch_dse,0,\"skipped: {e}\"")
+    else:
+        print("arch_dse,0,\"not measured: no dry-run records\"")
 
-    # roofline table from dry-run artifacts (if present)
-    try:
-        from repro.launch.roofline import load_table
-        rows = load_table()
-        if rows:
-            worst = min(rows, key=lambda r: r["roofline_fraction"])
-            bound = {}
-            for r in rows:
-                bound[r["bottleneck"]] = bound.get(r["bottleneck"], 0) + 1
-            print(f"roofline_single_pod,0,\"{len(rows)} cells; bottlenecks "
-                  f"{bound}; worst fraction {worst['roofline_fraction']:.2%} "
-                  f"({worst['arch']}/{worst['shape']})\"")
-        else:
-            print("roofline_single_pod,0,\"no dry-run artifacts\"")
-    except Exception as e:  # artifacts may not exist in fresh checkouts
-        print(f"roofline_single_pod,0,\"skipped: {e}\"")
+    from repro.launch.roofline import load_table
+    rows = load_table()
+    if rows:
+        worst = min(rows, key=lambda r: r["roofline_fraction"])
+        bound = {}
+        for r in rows:
+            bound[r["bottleneck"]] = bound.get(r["bottleneck"], 0) + 1
+        print(f"roofline_single_pod,0,\"{len(rows)} cells; bottlenecks "
+              f"{bound}; worst fraction {worst['roofline_fraction']:.2%} "
+              f"({worst['arch']}/{worst['shape']})\"")
+    else:
+        print("roofline_single_pod,0,\"not measured: no dry-run records\"")
 
 
 if __name__ == '__main__':

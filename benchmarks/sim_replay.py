@@ -40,6 +40,32 @@ def _time(fn, repeats: int) -> float:
     return (time.perf_counter() - t0) / repeats
 
 
+# the replayed task: two levels of two buckets each (S = 4 slots)
+REPLAY_TASK_LEVELS = (
+    ("L1", 1 << 20, ((0.6, 1.2e9, 2e-6), (0.4, 5e8, 1e-4))),
+    ("L2", 64 << 20, ((0.5, 1e9, 1e-3), (0.5, 2e9, 3e-6))),
+)
+REPLAY_PHASES = ("prefill", "decode")
+
+
+def replay_grid(table, J: int, bins: int, seed: int = 0):
+    """The synthetic replay problem: ``(cols, idx (J, S), traces)`` with
+    uniform random table rows per slot — the same gather + scan cost profile
+    as a real top-K re-rank, but with a controllable J."""
+    from repro.core.select import Bucket, LevelReq, TaskReq
+    from repro.sim import task_traces
+    from repro.sim.rerank import sim_cols
+
+    task = TaskReq("bench", "bench", {
+        name: LevelReq(name, cap, tuple(Bucket(*b) for b in buckets))
+        for name, cap, buckets in REPLAY_TASK_LEVELS})
+    traces = task_traces(task, phases=REPLAY_PHASES, n_bins=bins)
+    S = sum(len(b) for _, _, b in REPLAY_TASK_LEVELS)
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, len(table), size=(J, S)).astype(np.int32)
+    return sim_cols(table), idx, traces
+
+
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
@@ -49,12 +75,13 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
 
     import jax
+    from repro import compile_cache
     from repro.api import Compiler, DesignTable, design_space
     from repro.core import gainsight
-    from repro.core.select import Bucket, LevelReq, TaskReq
-    from repro.sim import simulate_traces, task_traces
+    from repro.sim import simulate_traces
     from repro.sim.engine import SIM_METRICS
-    from repro.sim.rerank import sim_cols
+
+    compile_cache.enable()
 
     table = DesignTable.build(design_space(), cache=args.cache)
 
@@ -67,21 +94,10 @@ def main(argv=None) -> dict:
     simulate_ms = (time.perf_counter() - t0) / len(gainsight.TASKS) * 1e3
 
     # --- throughput: one big synthetic replay grid -------------------------
-    # (uniform random rows per slot — the same gather + scan cost profile as
-    # a real top-K re-rank, but with a controllable J)
     J = 2_000 if args.quick else 50_000
     bins = 16 if args.quick else 32
-    S = 4
-    task = TaskReq("bench", "bench", {
-        "L1": LevelReq("L1", 1 << 20, (Bucket(0.6, 1.2e9, 2e-6),
-                                       Bucket(0.4, 5e8, 1e-4))),
-        "L2": LevelReq("L2", 64 << 20, (Bucket(0.5, 1e9, 1e-3),
-                                        Bucket(0.5, 2e9, 3e-6)))})
-    phases = ("prefill", "decode")
-    traces = task_traces(task, phases=phases, n_bins=bins)
-    rng = np.random.default_rng(0)
-    idx = rng.integers(0, len(table), size=(J, S)).astype(np.int32)
-    cols = sim_cols(table)
+    cols, idx, traces = replay_grid(table, J, bins)
+    S = idx.shape[1]
     reps = 3 if args.quick else 10
 
     t_xla = _time(lambda: simulate_traces(cols, idx, traces, backend="xla"),
@@ -102,7 +118,7 @@ def main(argv=None) -> dict:
         "grid": J,
         "slots": S,
         "bins": bins,
-        "phases": list(phases),
+        "phases": list(REPLAY_PHASES),
         "xla": {
             "latency_s": round(t_xla, 6),
             "comps_per_s": round(J / t_xla, 1),

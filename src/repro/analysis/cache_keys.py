@@ -52,6 +52,8 @@ INGREDIENT_SPECS: Tuple[Tuple[str, str, Tuple[str, ...]], ...] = (
      ("_hash_seed", "corners_fingerprint", "AXIS_NAMES")),
     ("src/repro/api.py", "DesignTable.grid_hash",
      ("_hash_seed", "corners_fingerprint", "AXIS_NAMES")),
+    ("src/repro/api.py", "_hash_seed",
+     ("_physics_fingerprint", "_device_stamp")),
     ("src/repro/core/corners.py", "corners_fingerprint", ("fingerprint",)),
     ("src/repro/hetero/cache.py", "report_key", ("_task_fingerprint",)),
 )

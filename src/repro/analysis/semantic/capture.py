@@ -41,17 +41,9 @@ def _kernel_name(kernel) -> str:
 
 
 def dimension_semantics_of(compiler_params) -> Optional[Tuple[str, ...]]:
-    """Extract dimension_semantics across the compat spellings: the
-    CompilerParams/TPUCompilerParams dataclass, or the {"mosaic": {...}}
-    dict fallback (see ``repro.compat.tpu_compiler_params``)."""
-    if compiler_params is None:
-        return None
-    if isinstance(compiler_params, dict):
-        inner = compiler_params.get("mosaic", compiler_params)
-        ds = inner.get("dimension_semantics") if isinstance(inner, dict) \
-            else None
-    else:
-        ds = getattr(compiler_params, "dimension_semantics", None)
+    """The ``dimension_semantics`` of a ``pltpu.CompilerParams`` (None when
+    no params or no semantics were given)."""
+    ds = getattr(compiler_params, "dimension_semantics", None)
     return tuple(ds) if ds is not None else None
 
 

@@ -103,9 +103,19 @@ def _physics_fingerprint() -> str:
     return h.hexdigest()[:16]
 
 
+def _device_stamp() -> str:
+    """Platform and kind of the device that characterizes: a table built on
+    one device is never served on another, whose float32 exp/log/divide
+    round differently."""
+    import jax
+    device = jax.devices()[0]
+    return f"{device.platform}:{device.device_kind}"
+
+
 def _hash_seed() -> "hashlib._Hash":
     return hashlib.sha256(
-        f"schema={_SCHEMA_VERSION};physics={_physics_fingerprint()}".encode())
+        f"schema={_SCHEMA_VERSION};physics={_physics_fingerprint()};"
+        f"device={_device_stamp()}".encode())
 
 # how many times the vmap characterization actually ran (cache-hit proof);
 # lives on the repro.obs metrics registry, read through the thin alias below
@@ -282,13 +292,15 @@ class DesignTable:
 
     def save(self, path: Union[str, Path]) -> Path:
         """Persist axes + metrics to ``path`` (npz, stamped with the grid
-        hash, the operating corners, and the physics-source fingerprint)."""
+        hash, the operating corners, the physics-source fingerprint, and
+        the device that characterized it)."""
         path = Path(path)
         path.parent.mkdir(parents=True, exist_ok=True)
         payload = {f"axis_{k}": v for k, v in self._axes.items()}
         payload.update({f"metric_{k}": v for k, v in self._metrics.items()})
         meta = {"schema": _SCHEMA_VERSION, "grid_hash": self.grid_hash,
                 "physics": _physics_fingerprint(),
+                "device": _device_stamp(),
                 "corners": [[float(op.vdd), float(op.temp_k), op.corner]
                             for op in self._corners]}
         np.savez(path, __meta__=json.dumps(meta), **payload)
@@ -418,7 +430,7 @@ class DesignTable:
     @property
     def grid_hash(self) -> str:
         """Cache key: config grid (axes) + operating corners +
-        physics-source fingerprint."""
+        physics-source fingerprint + device stamp."""
         h = _hash_seed()
         h.update(corners_mod.corners_fingerprint(self._corners).encode())
         for name in self.AXIS_NAMES:
@@ -520,7 +532,7 @@ class DesignTable:
 def grid_hash(configs: Sequence[MacroConfig], corners=None) -> str:
     """Cache key of a (config grid, corners) pair without characterizing it
     (includes the physics-source fingerprint, so model edits invalidate old
-    caches)."""
+    caches, and the device stamp, so no device is served another's table)."""
     h = _hash_seed()
     h.update(corners_mod.corners_fingerprint(
         corners_mod.as_corners(corners)).encode())

@@ -1,123 +1,32 @@
-"""Version-portable jax substrate (supported range: jax 0.4.30 – 0.7.x).
+"""Mesh and sharding helpers shared by the sharded paths and the models.
 
-Every jax API this repo touches that drifted across the 0.4.x → 0.7.x
-releases goes through here, and ONLY here — no module outside this file may
-reference ``jax.sharding.AxisType``, ``pltpu.CompilerParams`` /
-``pltpu.TPUCompilerParams``, or construct a ``Mesh`` directly.  The drift
-this file absorbs:
-
-  =====================  ==========================  =========================
-  API                    old spelling (0.4.x)        new spelling (0.6+)
-  =====================  ==========================  =========================
-  Pallas TPU params      pltpu.TPUCompilerParams     pltpu.CompilerParams
-  mesh axis types        (kwarg does not exist)      jax.make_mesh(...,
-                                                       axis_types=(AxisType
-                                                       .Auto, ...))
-  shard_map              jax.experimental.shard_map  jax.shard_map
-                           (check_rep=...)             (check_vma=...)
-  =====================  ==========================  =========================
-
-Resolution happens at CALL time, not import time, so tests can monkeypatch
-either spelling onto the live jax modules and both code paths stay covered
-on whichever jax is pinned (see tests/test_compat.py).
+Written against the installed jax (0.9). Every mesh this repo builds goes
+through ``make_mesh``, so the axis type of every mesh axis is set in one
+place.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
-import numpy as np
-
-
-def jax_version() -> tuple:
-    """(major, minor, patch) ints, tolerant of dev/rc suffixes."""
-    parts = []
-    for p in jax.__version__.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-def _accepts(fn, name: str) -> bool:
-    try:
-        return name in inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return False
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params
-# ---------------------------------------------------------------------------
-
-
-def tpu_compiler_params(**kwargs):
-    """Build Pallas-TPU compiler params under either spelling.
-
-    jax >= 0.6.2 renamed ``TPUCompilerParams`` -> ``CompilerParams``;
-    releases before the dataclass existed take a plain ``{"mosaic": {...}}``
-    dict.  Typical use::
-
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return {"mosaic": dict(kwargs)}
-    return cls(**kwargs)
-
-
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
-
-
-def axis_types(kind: Optional[str], n: int):
-    """``n``-tuple of ``jax.sharding.AxisType`` members, or None where the
-    enum does not exist (jax < 0.6 treats every axis as implicitly auto)."""
-    if kind is None:
-        return None
-    enum = getattr(jax.sharding, "AxisType", None)
-    if enum is None:
-        return None
-    member = getattr(enum, kind.capitalize(), None)
-    return None if member is None else (member,) * n
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              kind: Optional[str] = "auto", devices=None):
-    """``jax.make_mesh`` across the ``axis_types`` drift.
-
-    ``kind`` is the symbolic axis type ("auto" / "explicit" / "manual")
-    applied to every axis; it degrades to nothing where the enum or the
-    kwarg is missing.  Falls back to hand-arranged ``jax.sharding.Mesh``
-    construction on releases that predate ``jax.make_mesh`` itself.
-    """
-    types = axis_types(kind, len(axis_names))
-    fn = getattr(jax, "make_mesh", None)
-    if fn is not None:
-        kw = {}
-        if devices is not None:
-            kw["devices"] = devices
-        if types is not None and _accepts(fn, "axis_types"):
-            kw["axis_types"] = types
-        return fn(tuple(axis_shapes), tuple(axis_names), **kw)
-    n = int(np.prod(axis_shapes))
-    devs = list(devices) if devices is not None else jax.devices()[:n]
-    return jax.sharding.Mesh(
-        np.asarray(devs).reshape(tuple(axis_shapes)), tuple(axis_names))
+              kind: str = "auto", devices=None):
+    """``jax.make_mesh`` with every axis of type ``kind`` ("auto" /
+    "explicit" / "manual", a ``jax.sharding.AxisType`` member name)."""
+    types = (getattr(jax.sharding.AxisType, kind.capitalize()),) \
+        * len(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=types, devices=devices)
 
 
 def current_mesh():
     """The physical mesh activated by ``with mesh:``, or None.
 
     This is the one private-API touchpoint (``thread_resources`` has no
-    public accessor on 0.4.x); isolating it here keeps the model code free
-    of ``jax._src`` imports."""
+    public accessor); isolating it here keeps the model code free of
+    ``jax._src`` imports."""
     try:
         from jax._src import mesh as mesh_lib
         env_mesh = mesh_lib.thread_resources.env.physical_mesh
@@ -126,29 +35,6 @@ def current_mesh():
     if env_mesh is None or getattr(env_mesh, "empty", True):
         return None
     return env_mesh
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: Optional[bool] = None):
-    """``jax.shard_map`` (>= 0.6) / ``jax.experimental.shard_map`` (0.4.x).
-
-    ``check_rep`` maps onto whichever replication-check kwarg the installed
-    release spells (``check_vma`` after the rename); None leaves the
-    default."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # 0.4.x
-    kw = {}
-    if check_rep is not None:
-        if _accepts(fn, "check_vma"):
-            kw["check_vma"] = check_rep
-        elif _accepts(fn, "check_rep"):
-            kw["check_rep"] = check_rep
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 
 # ---------------------------------------------------------------------------

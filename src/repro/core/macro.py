@@ -47,10 +47,19 @@ VEC_FIELDS = ("mem_type", "word_size", "num_words", "banks", "level_shift",
 
 
 def auto_mux(word_size, num_words):
-    """Pick a power-of-2 column-mux ratio that squares the array."""
-    target = jnp.sqrt(num_words / jnp.maximum(word_size, 1.0))
-    m = 2.0 ** jnp.round(jnp.log2(jnp.maximum(target, 1.0)))
-    return jnp.clip(m, 1.0, 8.0)
+    """Pick a power-of-2 column-mux ratio that squares the array:
+    2^round(log2(nw/wz) / 2), clipped to [1, 8].
+
+    A power-of-two ratio puts the exponent exactly on a half-integer
+    (0.5, 1.5, 2.5), where rounding is a tie. The log2 is therefore taken
+    from ``frexp``'s exponent (log2 of the mantissa is exactly 0 there) and
+    the power built with ``ldexp``, so the tie breaks the same way on every
+    backend instead of following the last ulp of a float sqrt/log2."""
+    mant, exp = jnp.frexp(num_words / jnp.maximum(word_size, 1.0))
+    half_log2 = 0.5 * ((exp - 1).astype(jnp.float32)
+                       + jnp.log2(2.0 * mant))
+    k = jnp.round(jnp.maximum(half_log2, 0.0)).astype(jnp.int32)
+    return jnp.clip(jnp.ldexp(jnp.float32(1.0), k), 1.0, 8.0)
 
 
 def geometry(vec):
@@ -99,7 +108,7 @@ def macro_area(g):
              + cols * jnp.where(g["is_gc"] > 0, tech.PREDIS_AREA,
                                 tech.PRECH_AREA))
     # data + address DFFs (dual-port GC: separate addr regs per port)
-    n_addr = jnp.ceil(jnp.log2(jnp.maximum(g["nw"], 2.0)))
+    n_addr = periphery.ceil_log2(jnp.maximum(g["nw"], 2.0))
     a_dff = (2 * wz + n_addr * (1.0 + dual)) * tech.DFF_AREA
 
     a_ctrl, _, _, _ = periphery.control()

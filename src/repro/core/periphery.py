@@ -16,6 +16,14 @@ INV_LEAK = 60e-12    # A per um of gate width, periphery std-cell average
 INV_CIN = 1.5e-15    # F per um of input gate width
 
 
+def ceil_log2(x):
+    """ceil(log2(x)) for x >= 1, exact on every backend: the exponent comes
+    from ``frexp`` instead of a float log2, which on some backends lands
+    one ulp above an exact power of two and adds a whole stage."""
+    mant, exp = jnp.frexp(x)
+    return (exp - (mant == 0.5)).astype(jnp.float32)
+
+
 def wordline_rc(cols, cell_w, w_access):
     """C and R of one wordline spanning `cols` cells."""
     c = cols * (w_access * tech.C_GATE_PER_UM + cell_w * tech.C_WIRE_PER_UM)
@@ -34,7 +42,7 @@ def decoder(rows, tp=None):
     energy/access, leakage). ``tp`` = operating corner (switching energies
     scale with vdd^2)."""
     tp = corners.resolve(tp)
-    n_addr = jnp.ceil(jnp.log2(jnp.maximum(rows, 2.0)))
+    n_addr = ceil_log2(jnp.maximum(rows, 2.0))
     stages = 2.0 + jnp.ceil(n_addr / 3.0)          # predecode depth
     area_um2 = rows * tech.GATE_AREA + n_addr * 4.0 * tech.GATE_AREA
     delay_s = stages * tech.T_GATE
@@ -89,7 +97,7 @@ def column_mux(mux_ratio, tp=None):
     tp = corners.resolve(tp)
     is_mux = (mux_ratio > 1).astype(jnp.float32) if hasattr(mux_ratio, "astype") \
         else float(mux_ratio > 1)
-    stages = jnp.ceil(jnp.log2(jnp.maximum(mux_ratio, 1.0)))
+    stages = ceil_log2(jnp.maximum(mux_ratio, 1.0))
     area_per_col_um2 = 0.9 * is_mux
     delay_s = stages * tech.T_MUX
     energy_j = stages * 0.8e-15 * tp.vdd ** 2

@@ -16,7 +16,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core import bitcells, corners, devices
+from repro.core import bitcells, corners, devices, tech
 
 T_START, T_END, PTS_PER_DECADE = 1e-9, 1e7, 30
 # plain math, not jnp: computing this with jnp.log10 dispatched device work
@@ -116,6 +116,22 @@ def retention_estimate(cell: bitcells.BitcellParams, level_shift=0, tp=None):
     dv = jnp.maximum(v0 - read_margin_threshold(cell, tp=tp), 0.0)
     i0 = leak_current(cell, v0, tp)
     return cell.c_sn * dv / jnp.maximum(i0, 1e-30)
+
+
+def pack_cell(name: str, level_shift: int) -> list:
+    """One row of the Pallas retention kernel's packed parameters
+    (``repro.kernels.ref.N_FIELDS``) for bitcell ``name`` (a key of
+    ``bitcells.BITCELLS``) at the nominal corner: the write device's
+    subthreshold leak, the read device's gate leak, the storage node, and
+    the written level and read-margin threshold."""
+    c = bitcells.BITCELLS[name]
+    wd = devices.take_device(bitcells.DEVICE_STACK, int(c.write_dev))
+    rd = devices.take_device(bitcells.DEVICE_STACK, int(c.read_dev))
+    return [float(wd.vt), float(wd.n), float(wd.ispec), float(wd.eta_dibl),
+            float(wd.i_floor), float(rd.j_gate * c.w_read / tech.VDD),
+            float(c.c_sn), float(c.w_write),
+            float(bitcells.sn_high_level(c, level_shift)),
+            float(read_margin_threshold(c))]
 
 
 retention_time_batch = jax.jit(jax.vmap(retention_time, in_axes=(0, 0)))

@@ -104,6 +104,21 @@ def composition_eval_count() -> int:
     return _C_EVALS.value
 
 
+def slot_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the trailing slot axis, one slot at a time in slot order
+    (jnp or numpy arrays alike).
+
+    A ``jnp.sum`` reduction may be reassociated, and on TPU its order
+    follows the array's layout, which depends on how many compositions are
+    in the call: the same composition then prices differently in a sharded,
+    batched or single-composition call. A left fold of elementwise adds
+    fixes the order, so every such call agrees bit for bit."""
+    acc = x[..., 0]
+    for s in range(1, x.shape[-1]):
+        acc = acc + x[..., s]
+    return acc
+
+
 def score_kernel(idx: jnp.ndarray, cols: Dict[str, jnp.ndarray],
                  cap_bits: jnp.ndarray, f_req: jnp.ndarray
                  ) -> Dict[str, jnp.ndarray]:
@@ -126,17 +141,15 @@ def score_kernel(idx: jnp.ndarray, cols: Dict[str, jnp.ndarray],
     tiles = jnp.ceil(cap_bits[None, :] / bits)           # macros per slot
     inf = jnp.float32(jnp.inf)
 
-    area_um2 = jnp.sum(jnp.where(bad, inf, tiles * take("area_um2")), axis=1)
-    p_static_w = jnp.sum(
-        jnp.where(bad, inf,
-                  tiles * (take("p_leak_w") + take("p_refresh_w"))), axis=1)
-    p_dyn_w = jnp.sum(jnp.where(bad, inf, take("e_read_j") * f_req[None, :]),
-                      axis=1)
+    area_um2 = slot_sum(jnp.where(bad, inf, tiles * take("area_um2")))
+    p_static_w = slot_sum(
+        jnp.where(bad, inf, tiles * (take("p_leak_w") + take("p_refresh_w"))))
+    p_dyn_w = slot_sum(jnp.where(bad, inf, take("e_read_j") * f_req[None, :]))
     bw_margin = jnp.min(
         jnp.where(bad, 0.0,
                   take("f_op_hz") / jnp.maximum(f_req[None, :], 1.0)), axis=1)
-    capacity_bits = jnp.sum(jnp.where(bad, 0.0, tiles * bits), axis=1)
-    overprov = capacity_bits / jnp.maximum(jnp.sum(cap_bits), 1.0)
+    capacity_bits = slot_sum(jnp.where(bad, 0.0, tiles * bits))
+    overprov = capacity_bits / jnp.maximum(slot_sum(cap_bits), 1.0)
     return {
         "area_um2": area_um2,
         "p_static_w": p_static_w,
@@ -154,7 +167,9 @@ _score_jit = jax.jit(score_kernel)
 def _score_interpret(idx, cols, cap_bits, f_req) -> Dict[str, np.ndarray]:
     """Pure-numpy float32 mirror of ``score_kernel`` — the oracle the
     registry-level interpret-vs-xla divergence sweep
-    (``tests/test_backend_divergence.py``) drives against the jit path."""
+    (``tests/test_backend_divergence.py``) drives against the jit path.
+    Slots are summed by the same ``slot_sum`` left fold (``np.sum`` sums
+    pairwise from 8 slots on, a different order)."""
     idx = np.asarray(idx)
     bad = idx < 0
     safe = np.maximum(idx, 0)
@@ -167,22 +182,18 @@ def _score_interpret(idx, cols, cap_bits, f_req) -> Dict[str, np.ndarray]:
     bits = np.maximum(take("bits"), np.float32(1.0))
     tiles = np.ceil(slot_cap_bits[None, :] / bits)
     inf = np.float32(np.inf)
-    area_um2 = np.sum(np.where(bad, inf, tiles * take("area_um2")),
-                      axis=1, dtype=np.float32)
-    p_static_w = np.sum(
-        np.where(bad, inf, tiles * (take("p_leak_w") + take("p_refresh_w"))),
-        axis=1, dtype=np.float32)
-    p_dyn_w = np.sum(
-        np.where(bad, inf, take("e_read_j") * slot_f_req_hz[None, :]),
-        axis=1, dtype=np.float32)
+    area_um2 = slot_sum(np.where(bad, inf, tiles * take("area_um2")))
+    p_static_w = slot_sum(
+        np.where(bad, inf, tiles * (take("p_leak_w") + take("p_refresh_w"))))
+    p_dyn_w = slot_sum(
+        np.where(bad, inf, take("e_read_j") * slot_f_req_hz[None, :]))
     bw_margin = np.min(
         np.where(bad, np.float32(0.0),
                  take("f_op_hz") / np.maximum(slot_f_req_hz[None, :],
                                               np.float32(1.0))), axis=1)
-    capacity_bits = np.sum(np.where(bad, np.float32(0.0), tiles * bits),
-                           axis=1, dtype=np.float32)
+    capacity_bits = slot_sum(np.where(bad, np.float32(0.0), tiles * bits))
     overprov = capacity_bits / np.maximum(
-        np.sum(slot_cap_bits, dtype=np.float32), np.float32(1.0))
+        slot_sum(slot_cap_bits), np.float32(1.0))
     return {
         "area_um2": area_um2,
         "p_static_w": p_static_w,

@@ -12,8 +12,7 @@ Selection order (``resolve_backend``):
   1. explicit ``backend=`` argument
   2. a ``use_backend(...)`` context override (innermost wins)
   3. ``REPRO_KERNEL_BACKEND`` env var ("tpu" / "interpret" / "xla")
-  4. legacy ``REPRO_PALLAS_INTERPRET=1`` (kept for existing launch scripts)
-  5. "tpu" when ``jax.default_backend()`` is a TPU, else "xla"
+  4. "tpu" when ``jax.default_backend()`` is a TPU, else "xla"
 
 A resolved backend with no registered implementation falls back to "xla",
 so ops stay callable on CPU even when only the reference path exists.
@@ -97,8 +96,6 @@ def resolve_backend(explicit: Optional[str] = None) -> str:
             raise ValueError(
                 f"REPRO_KERNEL_BACKEND={env!r} invalid; valid: {BACKENDS}")
         return env
-    if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
-        return "interpret"
     return "tpu" if jax.default_backend() == "tpu" else "xla"
 
 

@@ -1,10 +1,9 @@
 """Public kernel entry points, dispatched through ``repro.kernels.backend``.
 
-On TPU the pallas_call path runs natively; on CPU (this container, including
-the 512-device dry-run) the pure-jnp oracle runs instead so the AOT compile
-stays tractable.  Backend selection is centralized in
-``backend.resolve_backend`` (``REPRO_KERNEL_BACKEND`` env var, legacy
-``REPRO_PALLAS_INTERPRET=1``, else device-based).
+On TPU the pallas_call path runs natively; on CPU (including the 512-device
+dry-run) the pure-jnp oracle runs instead so the AOT compile stays
+tractable.  Backend selection is centralized in ``backend.resolve_backend``
+(``REPRO_KERNEL_BACKEND`` env var, else device-based).
 """
 from __future__ import annotations
 

@@ -11,7 +11,8 @@ _NEG = -0.7 * jnp.finfo(jnp.float32).max
 # ---------------------------------------------------------------------------
 # retention transient (oracle for retention_kernel)
 # ---------------------------------------------------------------------------
-# packed config rows: [vt, n, ispec, eta, i_floor, jg_coef, c_sn, w, v0, v_min]
+# packed config rows (repro.core.retention.pack_cell):
+# [vt, n, ispec, eta, i_floor, jg_coef, c_sn, w, v0, v_min]
 N_FIELDS = 10
 
 

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 BLOCK_D = 512
 CHUNK_T = 128
@@ -72,7 +71,7 @@ def ssm_scan_pallas(x, dt, A, Bc, Cc, D, *, block_d=BLOCK_D, chunk=CHUNK_T,
         out_specs=pl.BlockSpec((1, chunk, block_d), lambda b, d, c: (b, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, di), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x.astype(jnp.float32), dt.astype(jnp.float32), Bc.astype(jnp.float32),

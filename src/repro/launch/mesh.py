@@ -3,8 +3,8 @@
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — the dry-run must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init.
-Mesh construction goes through ``repro.compat.make_mesh`` so the axis-type
-annotation degrades gracefully across the jax 0.4.x → 0.7.x drift.
+Mesh construction goes through ``repro.compat.make_mesh``, which sets one
+axis type for every axis.
 """
 from __future__ import annotations
 

@@ -105,8 +105,6 @@ def _moe_block_shardmap(p, x, cfg, mesh):
     partial outputs."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     B, S, d = x.shape
     E = cfg.num_experts
     data_axes = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
@@ -144,7 +142,7 @@ def _moe_block_shardmap(p, x, cfg, mesh):
     shared_p = p.get("shared")
     shared_specs = ({"wg": P(None, "model"), "wu": P(None, "model"),
                      "wd": P("model", None)} if shared_p is not None else None)
-    y, load = shard_map(
+    y, load = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp[0] if len(data_axes) == 1 else data_axes, None, None),
                   P(None, None), P(None),
@@ -152,7 +150,7 @@ def _moe_block_shardmap(p, x, cfg, mesh):
                   P("model", None, None), shared_specs),
         out_specs=(P(data_axes if len(data_axes) > 1 else data_axes[0],
                      None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"].astype(jnp.float32), p["bias"], p["wg"], p["wu"],
       p["wd"], shared_p)
     aux = {"load": load,

@@ -48,8 +48,6 @@ def make_compressed_grad_fn(loss_fn, mesh, axis_name="data"):
 
     Returns grad_fn(params, batch, resid) -> (loss_mean, grads_mean, resid').
     Params are replicated across `axis_name`; batch is sharded on dim 0."""
-    from repro.compat import shard_map
-
     def local(params, batch, resid):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         g_mean, new_resid = ef_int8_psum_mean(grads, resid, axis_name)
@@ -58,7 +56,7 @@ def make_compressed_grad_fn(loss_fn, mesh, axis_name="data"):
     def grad_fn(params, batch, resid):
         batch_spec = jax.tree.map(lambda _: P(axis_name), batch)
         rep = jax.tree.map(lambda _: P(), params)
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(rep, batch_spec, rep),
             out_specs=(P(), rep, rep),

@@ -2,7 +2,7 @@
 
 ``shard_leading`` runs a batched pure function with its first argument's
 leading axis split across every visible device via ``repro.compat.make_mesh``
-+ ``repro.compat.shard_map``; remaining arguments are replicated. The grid is
++ ``jax.shard_map``; remaining arguments are replicated. The grid is
 padded to a device-count multiple and un-padded on the way out, so callers
 never see the device count. On a 1-device host it degrades to a plain call —
 the result is bit-identical either way (same kernel, same math, only the
@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 
 GRID_AXIS = "grid"
 CORNER_AXIS = "corner"
@@ -68,10 +68,10 @@ def shard_leading(fn, x, *rest, devices: Optional[Sequence] = None,
         _C_SHARD.inc()
         mesh = make_mesh((n_dev,), (axis_name,), devices=devs)
         xp, n = pad_to_multiple(jnp.asarray(x), n_dev)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(axis_name),) + (P(),) * len(rest),
-            out_specs=P(axis_name), check_rep=False)
+            out_specs=P(axis_name), check_vma=False)
         out = sharded(xp, *rest)
         return jax.tree.map(lambda leaf: leaf[:n], out)
 
@@ -119,9 +119,9 @@ def shard2d(fn, x, y, *rest, devices: Optional[Sequence] = None,
             lambda leaf: pad_to_multiple(jnp.asarray(leaf), ways_x)[0], x)
         yp = jax.tree.map(
             lambda leaf: pad_to_multiple(jnp.asarray(leaf), ways_y)[0], y)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(ax_x), P(ax_y)) + (P(),) * len(rest),
-            out_specs=P(ax_y, ax_x), check_rep=False)
+            out_specs=P(ax_y, ax_x), check_vma=False)
         out = sharded(xp, yp, *rest)
         return jax.tree.map(lambda leaf: leaf[:n_y, :n_x], out)

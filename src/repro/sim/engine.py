@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.hetero.system import slot_sum
 from repro.kernels import backend as _backend
 from repro.sim import refresh as refresh_mod
 from repro.sim.trace import Trace
@@ -200,11 +201,12 @@ def _sim_phase_one(params, slot, xs, consts):
         age = (age + t_bin) * (1.0 - turn)
         carry = (
             age,
-            e_dyn + jnp.sum(reads * p["e_read_j"] + wops * p["e_write_j"]),
-            e_ref + jnp.sum(refr * (p["e_read_j"] + p["e_write_j"])),
-            e_rew + jnp.sum(rewr * p["e_write_j"]) * overhead,
+            e_dyn + slot_sum(reads * p["e_read_j"] + wops * p["e_write_j"]),
+            e_ref + slot_sum(refr * (p["e_read_j"] + p["e_write_j"])),
+            e_rew + slot_sum(rewr * p["e_write_j"]) * overhead,
             t_sim + t_bin * jnp.maximum(jnp.max(util), 1.0),
-            coll + jnp.sum(refr * jnp.minimum((reads + wops) / cap_ops, 1.0)),
+            coll + slot_sum(refr * jnp.minimum((reads + wops) / cap_ops,
+                                               1.0)),
             jnp.maximum(upk, jnp.max(util)),
             jnp.maximum(apk, jnp.max(age)),
             t_acc + t_bin,
@@ -217,7 +219,7 @@ def _sim_phase_one(params, slot, xs, consts):
     (age, e_dyn, e_ref, e_rew, t_sim, coll, upk, apk, _), _ = jax.lax.scan(
         step, carry0, xs)
     t_wall = jnp.sum(xs[0])
-    e_leak = jnp.sum(p["p_leak_w"] * p["tiles"]) * t_sim
+    e_leak = slot_sum(p["p_leak_w"] * p["tiles"]) * t_sim
     e_total = e_dyn + e_ref + e_rew + e_leak
     return {
         "e_dyn_j": e_dyn, "e_refresh_j": e_ref, "e_rewrite_j": e_rew,

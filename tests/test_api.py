@@ -57,6 +57,24 @@ def test_table_roundtrip_and_cache_hit(tmp_path):
     assert api.grid_hash(other) != t1.grid_hash
 
 
+def test_table_cache_misses_on_another_device(tmp_path, monkeypatch):
+    """A table saved under one device stamp is never served under another:
+    the stamp is part of the grid hash, and so of every hetero/sim report
+    key derived from it."""
+    cfgs = small_space()
+    monkeypatch.setattr(api, "_device_stamp", lambda: "tpu:TPU v5 lite")
+    DesignTable.build(cfgs, cache=tmp_path)
+    n_sweeps = api.characterize_call_count()
+    tpu_hash = api.grid_hash(cfgs)
+    DesignTable.build(cfgs, cache=tmp_path)
+    assert api.characterize_call_count() == n_sweeps      # same device: hit
+    monkeypatch.setattr(api, "_device_stamp", lambda: "cpu:cpu")
+    t = DesignTable.build(cfgs, cache=tmp_path)
+    assert api.characterize_call_count() == n_sweeps + 1  # other device: miss
+    assert t.grid_hash == api.grid_hash(cfgs) != tpu_hash
+    assert len(list(tmp_path.glob("table_*.npz"))) == 2
+
+
 def test_table_save_load_explicit(tmp_path):
     t = DesignTable.from_configs(small_space())
     path = t.save(tmp_path / "t.npz")

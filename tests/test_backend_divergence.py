@@ -13,23 +13,13 @@ import pytest
 
 import repro.hetero.system  # noqa: F401  (registers the compose_score op)
 import repro.sim.engine  # noqa: F401  (registers the sim_replay op)
-from repro.core import bitcells, devices, retention
+from repro.core import bitcells, retention
 from repro.kernels import backend, ops  # noqa: F401  (registers kernel ops)
 
 
 def _pack_cells(names, ls):
-    rows = []
-    for name in names:
-        c = bitcells.BITCELLS[name]
-        wd = devices.take_device(bitcells.DEVICE_STACK, int(c.write_dev))
-        rd = devices.take_device(bitcells.DEVICE_STACK, int(c.read_dev))
-        v0 = float(bitcells.sn_high_level(c, ls))
-        vmin = float(retention.read_margin_threshold(c))
-        rows.append([float(wd.vt), float(wd.n), float(wd.ispec),
-                     float(wd.eta_dibl), float(wd.i_floor),
-                     float(rd.j_gate * c.w_read / 1.1),
-                     float(c.c_sn), float(c.w_write), v0, vmin])
-    return jnp.asarray(rows, jnp.float32)
+    return jnp.asarray([retention.pack_cell(name, ls) for name in names],
+                       jnp.float32)
 
 
 def _attention_inputs():

@@ -1,10 +1,5 @@
-"""repro.compat must work under BOTH jax API spellings.
-
-The spelling the pinned jax does not provide is simulated by monkeypatching
-the live jax modules, so both code paths stay covered regardless of which
-jax is installed — the layer cannot silently rot when jax upgrades.
-Also covers the kernel backend registry (repro.kernels.backend).
-"""
+"""repro.compat mesh/sharding helpers and the kernel backend registry
+(repro.kernels.backend)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,73 +10,17 @@ from repro import compat
 from repro.kernels import backend
 
 
-# ------------------------------------------------------------ version parsing
-def test_jax_version_is_int_triple():
-    v = compat.jax_version()
-    assert len(v) == 3 and all(isinstance(p, int) for p in v)
-    assert v >= (0, 4, 0)
-
-
-# ------------------------------------------------------- tpu_compiler_params
-def test_compiler_params_native_spelling():
-    from jax.experimental.pallas import tpu as pltpu
-    params = compat.tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    assert isinstance(params, cls)
-    assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
-
-
-def test_compiler_params_new_spelling(monkeypatch):
-    from jax.experimental.pallas import tpu as pltpu
-
-    class FakeNew:
-        def __init__(self, **kw):
-            self.kw = kw
-
-    monkeypatch.setattr(pltpu, "CompilerParams", FakeNew, raising=False)
-    p = compat.tpu_compiler_params(dimension_semantics=("parallel",))
-    assert isinstance(p, FakeNew)
-    assert p.kw == {"dimension_semantics": ("parallel",)}
-
-
-def test_compiler_params_old_spelling(monkeypatch):
-    from jax.experimental.pallas import tpu as pltpu
-
-    class FakeOld:
-        def __init__(self, **kw):
-            self.kw = kw
-
-    monkeypatch.delattr(pltpu, "CompilerParams", raising=False)
-    monkeypatch.setattr(pltpu, "TPUCompilerParams", FakeOld, raising=False)
-    p = compat.tpu_compiler_params(dimension_semantics=("arbitrary",))
-    assert isinstance(p, FakeOld)
-    assert p.kw == {"dimension_semantics": ("arbitrary",)}
-
-
-def test_compiler_params_dict_fallback(monkeypatch):
-    from jax.experimental.pallas import tpu as pltpu
-    monkeypatch.delattr(pltpu, "CompilerParams", raising=False)
-    monkeypatch.delattr(pltpu, "TPUCompilerParams", raising=False)
-    p = compat.tpu_compiler_params(dimension_semantics=("parallel",))
-    assert p == {"mosaic": {"dimension_semantics": ("parallel",)}}
-
-
 # ------------------------------------------------------------------ make_mesh
 def test_make_mesh_native():
     mesh = compat.make_mesh((1,), ("data",))
     assert mesh.axis_names == ("data",)
     assert mesh.shape["data"] == 1
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
 
 
 def test_make_mesh_new_spelling_passes_axis_types(monkeypatch):
-    """When jax grows AxisType + the axis_types kwarg, compat must pass it."""
+    """compat.make_mesh hands jax.make_mesh one AxisType per axis."""
     recorded = {}
-
-    class FakeAxisType:
-        Auto = "auto-member"
-        Explicit = "explicit-member"
 
     def fake_make_mesh(axis_shapes, axis_names, *, devices=None,
                        axis_types=None):
@@ -89,65 +28,13 @@ def test_make_mesh_new_spelling_passes_axis_types(monkeypatch):
                         axis_types=axis_types)
         return "fake-mesh"
 
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType, raising=False)
     monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
     assert compat.make_mesh((2, 4), ("data", "model")) == "fake-mesh"
+    auto = jax.sharding.AxisType.Auto
     assert recorded == {"shapes": (2, 4), "names": ("data", "model"),
-                        "axis_types": ("auto-member", "auto-member")}
+                        "axis_types": (auto, auto)}
     assert compat.make_mesh((1,), ("x",), kind="explicit") == "fake-mesh"
-    assert recorded["axis_types"] == ("explicit-member",)
-
-
-def test_make_mesh_old_signature_drops_axis_types(monkeypatch):
-    """An old-style jax.make_mesh (no axis_types kwarg) must not receive one
-    even when the AxisType enum exists."""
-
-    class FakeAxisType:
-        Auto = "auto-member"
-
-    def fake_make_mesh(axis_shapes, axis_names, *, devices=None):
-        return ("fake-mesh", axis_shapes, axis_names)
-
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType, raising=False)
-    monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-    assert compat.make_mesh((1,), ("data",))[0] == "fake-mesh"
-
-
-def test_make_mesh_prehistoric_fallback(monkeypatch):
-    """Without jax.make_mesh at all, devices are arranged by hand."""
-    monkeypatch.delattr(jax, "make_mesh", raising=False)
-    mesh = compat.make_mesh((1,), ("data",))
-    assert isinstance(mesh, jax.sharding.Mesh)
-    assert mesh.axis_names == ("data",)
-
-
-def test_axis_types_none_when_enum_missing(monkeypatch):
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    assert compat.axis_types("auto", 2) is None
-    assert compat.axis_types(None, 2) is None
-
-
-# ------------------------------------------------------------------ shard_map
-def test_shard_map_executes():
-    mesh = compat.make_mesh((1,), ("data",))
-    f = compat.shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
-                         in_specs=P(), out_specs=P())
-    out = jax.jit(f)(jnp.ones((4,)))
-    np.testing.assert_allclose(np.asarray(out), np.ones(4))
-
-
-def test_shard_map_new_spelling_maps_check_rep_to_check_vma(monkeypatch):
-    recorded = {}
-
-    def fake_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        recorded.update(mesh=mesh, check_vma=check_vma)
-        return f
-
-    monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
-    fn = compat.shard_map(lambda x: x, mesh="m", in_specs=(), out_specs=(),
-                          check_rep=False)
-    assert fn(3) == 3
-    assert recorded == {"mesh": "m", "check_vma": False}
+    assert recorded["axis_types"] == (jax.sharding.AxisType.Explicit,)
 
 
 # --------------------------------------------------------------- current_mesh
@@ -186,14 +73,10 @@ def test_backend_registry_has_all_ops():
 
 def test_backend_resolution_order(monkeypatch):
     monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     assert backend.resolve_backend("interpret") == "interpret"
     monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
     assert backend.resolve_backend() == "interpret"
     monkeypatch.delenv("REPRO_KERNEL_BACKEND")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert backend.resolve_backend() == "interpret"
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
     expected = "tpu" if jax.default_backend() == "tpu" else "xla"
     assert backend.resolve_backend() == expected
     with pytest.raises(ValueError):
@@ -207,7 +90,6 @@ def test_backend_dispatch_agrees_across_backends(monkeypatch):
     """attention via xla and interpret backends must agree numerically."""
     import repro.kernels.ops as ops
     monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 2, 128, 64)), jnp.float32)
                for _ in range(3))

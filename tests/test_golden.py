@@ -1,11 +1,14 @@
 """Golden regression snapshot: the nominal-corner physics may not drift.
 
 ``tests/golden/table2.json`` freezes (a) the paper's Table-2 selections at
-the nominal operating point and (b) the full characterization of a small
-fixed config slice, with every metric stored as the exact float64 repr of
-the float32 the vmap pipeline produced. These tests diff live results
-against the snapshot **bit-for-bit** — an unintended edit to any physics
-module fails loudly here instead of silently shifting DSE winners.
+the nominal operating point, (b) the full characterization of a small
+fixed config slice and (c) that slice at the four named operating corners,
+with every metric stored as the float64 repr of the float32 the vmap
+pipeline produced. These tests diff live results against
+the snapshot under the golden contract (``update_golden.golden_diff``):
+labels and array geometry exact, float metrics within ``FLOAT_RTOL`` — an
+unintended edit to any physics module fails loudly here instead of
+silently shifting DSE winners.
 
 After an *intentional* physics change, regenerate with either
 
@@ -18,13 +21,14 @@ import json
 import sys
 from pathlib import Path
 
-import numpy as np
 import pytest
 
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO / "scripts"))
 
-from update_golden import GOLDEN_PATH, SLICE_KW, write_snapshot  # noqa: E402
+from update_golden import (GOLDEN_PATH, SLICE_KW,  # noqa: E402
+                           characterization_rows, corner_slice_table,
+                           golden_diff, write_snapshot)
 
 
 @pytest.fixture(scope="module")
@@ -49,36 +53,38 @@ def test_table2_selections_match_golden(golden):
 
 
 def test_characterization_slice_is_bit_for_bit(golden):
+    """The nominal slice under the golden contract: geometry exact, float
+    metrics within FLOAT_RTOL (the name predates the contract)."""
     from repro.api import DesignTable, design_space
     slice_kw = {k: tuple(v) for k, v in golden["slice"].items()}
     assert slice_kw == SLICE_KW, \
         "golden slice definition changed; regenerate the snapshot"
     table = DesignTable.from_configs(design_space(**slice_kw))
-    assert len(table) == len(golden["characterization"])
-    drift = []
-    for i, row in enumerate(golden["characterization"]):
-        live = table.row(i)
-        for k, v in row.items():
-            lv = live[k]
-            if isinstance(v, float):
-                same = float(lv) == v or (np.isnan(v) and np.isnan(float(lv)))
-            else:
-                same = str(lv) == str(v)
-            if not same:
-                drift.append(f"row {i} ({row['mem_type']} "
-                             f"{row['word_size']}x{row['num_words']}) "
-                             f"{k}: golden={v!r} live={lv!r}")
+    drift, _ = golden_diff(golden["characterization"],
+                           characterization_rows(table))
     assert not drift, (
-        "characterization drifted from the golden snapshot "
-        "(bit-for-bit):\n  " + "\n  ".join(drift[:20])
+        "characterization drifted from the golden snapshot:\n  "
+        + "\n  ".join(drift[:20])
         + "\nIf the physics change is intentional, regenerate via "
           "scripts/update_golden.py or pytest --update-golden.")
 
 
+def test_corner_slice_matches_golden(golden):
+    """Every ``<metric>@<corner>`` column of the slice at the four named
+    corners, under the golden contract."""
+    drift, _ = golden_diff(golden["corners"],
+                           characterization_rows(corner_slice_table()))
+    assert not drift, (
+        "corner characterization drifted from the golden snapshot:\n  "
+        + "\n  ".join(drift[:20]))
+
+
 def test_update_golden_roundtrips(tmp_path, golden):
-    """The update path rewrites a snapshot identical to a fresh build (so
-    --update-golden immediately followed by the diff test passes)."""
+    """The update path rewrites a snapshot that agrees with a fresh build
+    (so --update-golden immediately followed by the diff test passes)."""
     from update_golden import build_snapshot
     snap = build_snapshot()
     assert snap["table2"] == golden["table2"]
-    assert snap["characterization"] == golden["characterization"]
+    for key in ("characterization", "corners"):
+        drift, _ = golden_diff(golden[key], snap[key])
+        assert not drift, drift[:20]

@@ -4,8 +4,10 @@
 composition (``repro.core.gainsight.nlevel_task(3)``) under two settings —
 the default preference policy through the exhaustive grid, and the power
 objective through forced branch-and-bound — with every system metric stored
-as the exact float64 repr of the float32 the scoring kernel produced. These
-tests diff live results against the snapshot **bit-for-bit**, and separately
+as the float64 repr of the float32 the scoring kernel produced. These tests
+diff live results against the snapshot under the golden contract
+(``update_golden.golden_diff``: labels, picks, tiles and search exact,
+float metrics within ``FLOAT_RTOL``), and separately
 prove that the 2-level Table-2 results are unchanged through the N-level
 code path (``levels=("L1", "L2")``).
 
@@ -24,7 +26,8 @@ REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO / "scripts"))
 
 from update_golden import (NLEVEL_PATH, NLEVEL_POLICIES,  # noqa: E402
-                           compose_nlevel, write_nlevel_snapshot)
+                           build_nlevel_snapshot, golden_diff,
+                           write_nlevel_snapshot)
 
 
 @pytest.fixture(scope="module")
@@ -38,31 +41,12 @@ def golden(request):
 
 
 def test_nlevel_composition_is_bit_for_bit(golden):
+    """Under the golden contract: labels, picks, tiles and search exact,
+    float metrics within FLOAT_RTOL (the name predates the contract)."""
     assert set(golden["compositions"]) == set(NLEVEL_POLICIES), \
         "golden policy set changed; regenerate the snapshot"
-    drift = []
-    for name, kw in NLEVEL_POLICIES.items():
-        want = golden["compositions"][name]
-        rep = compose_nlevel(kw)
-        best = rep.best
-        if best.labels() != want["labels"]:
-            drift.append(f"{name}: labels {best.labels()} != "
-                         f"{want['labels']}")
-        for lvl, lc in best.levels.items():
-            if [p.config_idx for p in lc.picks] != want["picks"][lvl]:
-                drift.append(f"{name} {lvl}: picks drifted")
-            if list(lc.tiles) != want["tiles"][lvl]:
-                drift.append(f"{name} {lvl}: tiles drifted")
-        for k, v in want["metrics"].items():
-            if float(best.metrics[k]) != v:           # float-repr exact
-                drift.append(f"{name} metric {k}: "
-                             f"golden={v!r} live={best.metrics[k]!r}")
-        if rep.search != want["search"]:
-            drift.append(f"{name}: search engine {rep.search} != "
-                         f"{want['search']}")
-        if rep.n_space != want["n_space"]:
-            drift.append(f"{name}: n_space {rep.n_space} != "
-                         f"{want['n_space']}")
+    drift, _ = golden_diff(golden["compositions"],
+                           build_nlevel_snapshot()["compositions"])
     assert not drift, (
         "N-level composition drifted from the golden snapshot:\n  "
         + "\n  ".join(drift)

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import bitcells, devices, retention
+from repro.core import bitcells, retention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import attention_ref, retention_ref, ssm_scan_ref
 from repro.kernels.retention_kernel import retention_pallas
@@ -75,18 +75,8 @@ def test_ssm_scan_chunk_invariance():
 
 
 def _pack_cells(names, ls):
-    rows = []
-    for name in names:
-        c = bitcells.BITCELLS[name]
-        wd = devices.take_device(bitcells.DEVICE_STACK, int(c.write_dev))
-        rd = devices.take_device(bitcells.DEVICE_STACK, int(c.read_dev))
-        v0 = float(bitcells.sn_high_level(c, ls))
-        vmin = float(retention.read_margin_threshold(c))
-        rows.append([float(wd.vt), float(wd.n), float(wd.ispec),
-                     float(wd.eta_dibl), float(wd.i_floor),
-                     float(rd.j_gate * c.w_read / 1.1),
-                     float(c.c_sn), float(c.w_write), v0, vmin])
-    return jnp.asarray(rows, jnp.float32)
+    return jnp.asarray([retention.pack_cell(name, ls) for name in names],
+                       jnp.float32)
 
 
 def test_retention_kernel_matches_ref_and_core():

@@ -169,7 +169,7 @@ def test_ef_int8_psum_single_axis():
     """On a size-1 axis the compressed mean must equal plain quantization,
     and error feedback must carry the residual exactly."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from repro.compat import make_mesh
     mesh = make_mesh((1,), ("data",))
     rng = np.random.default_rng(0)
     g = {"w": jnp.asarray(rng.normal(size=(32, 32)), jnp.float32)}
@@ -178,7 +178,7 @@ def test_ef_int8_psum_single_axis():
     def f(g, r):
         return ef_int8_psum_mean(g, r, "data")
 
-    mean, resid = jax.jit(shard_map(f, mesh=mesh, in_specs=(P(), P()),
+    mean, resid = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
                                     out_specs=(P(), P())))(g, r)
     np.testing.assert_allclose(np.asarray(mean["w"] + resid["w"]),
                                np.asarray(g["w"]), rtol=1e-6, atol=1e-6)
@@ -191,13 +191,13 @@ def test_ef_int8_bias_vanishes_over_steps():
     """Accumulated compressed updates converge to accumulated true updates."""
     rng = np.random.default_rng(1)
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from repro.compat import make_mesh
     mesh = make_mesh((1,), ("data",))
     g_seq = [jnp.asarray(rng.normal(size=(16,)), jnp.float32) for _ in range(50)]
     r = {"w": jnp.zeros((16,))}
     acc_c = jnp.zeros((16,))
     acc_t = jnp.zeros((16,))
-    f = jax.jit(shard_map(lambda g, r: ef_int8_psum_mean(g, r, "data"),
+    f = jax.jit(jax.shard_map(lambda g, r: ef_int8_psum_mean(g, r, "data"),
                           mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P())))
     for g in g_seq:
         mean, r = f({"w": g}, r)

@@ -21,7 +21,8 @@ sys.path.insert(0, str(REPO / "scripts"))
 
 from _hypothesis_compat import given, settings, st  # noqa: E402
 from update_golden import (VDD_PATH, VDD_SWEEP_POINT,  # noqa: E402
-                           compose_vdd, write_vdd_snapshot)
+                           compose_vdd, full_sweep_tasks, golden_diff,
+                           vdd_tasks, write_vdd_snapshot)
 
 from repro.api import DesignTable, design_space  # noqa: E402
 from repro.core import bitcells, corners, gainsight, retention  # noqa: E402
@@ -47,26 +48,22 @@ def vdd_golden(request):
 # ------------------------------------------------------------ golden flips
 def test_vdd_sweep_flips_table2_winners_golden(vdd_golden):
     """The frozen cold-boost point must keep flipping exactly the same
-    Table-2 winners, with bit-identical picks and operating points."""
+    Table-2 winners, with identical labels, picks and operating points and
+    the winners' power within the golden contract."""
     assert vdd_golden["vdd_sweep_point"] == list(VDD_SWEEP_POINT)
-    flipped = []
-    for t in gainsight.TASKS:
-        want = vdd_golden["tasks"][str(t.task_id)]
-        base = compose_vdd(t, swept=False)
-        swept = compose_vdd(t, swept=True)
-        assert base.labels() == want["base_labels"], f"task {t.task_id}"
-        assert swept.labels() == want["swept_labels"], f"task {t.task_id}"
-        assert (swept.labels() != base.labels()) == want["flipped"]
-        got_picks = {lvl: [[p.family, p.config_idx,
-                            p.op.corner if p.op is not None else None,
-                            p.refresh_margin] for p in lc.picks]
-                     for lvl, lc in swept.best.levels.items()}
-        assert got_picks == want["picks"], f"task {t.task_id}"
-        assert float(base.best.metrics["p_w"]) == want["p_w"]["base"]
-        assert float(swept.best.metrics["p_w"]) == want["p_w"]["swept"]
-        if want["flipped"]:
-            flipped.append(t.task_id)
-    assert flipped, "the sweep point no longer flips any Table-2 winner"
+    live = vdd_tasks()
+    drift, _ = golden_diff(vdd_golden["tasks"], live)
+    assert not drift, drift[:20]
+    assert any(t["flipped"] for t in live.values()), \
+        "the sweep point no longer flips any Table-2 winner"
+
+
+def test_full_sweep_winners_match_golden(vdd_golden):
+    """Over the full 3-point x 2-margin sweep every Table-2 winner keeps
+    its labels, picks, operating points and tiles, and its power within
+    the golden contract."""
+    drift, _ = golden_diff(vdd_golden["full_sweep"], full_sweep_tasks())
+    assert not drift, drift[:20]
 
 
 def test_base_table2_parity_survives_the_sweep_machinery():
