@@ -53,15 +53,16 @@ def report_worst(name: str, worst) -> None:
 
 def run_phase(label: str, fn, *args):
     """Run one phase with the obs tracer on; print its wall time and the
-    jit traces the compile probes saw."""
+    programs it compiled or loaded from the compile cache."""
     from repro import obs
     obs.clear()
+    c0 = obs.value("jax.compiles")
     t0 = time.perf_counter()
     with obs.enabled_scope(True):
         out = fn(*args)
     wall = time.perf_counter() - t0
-    traces = sum(int(e["args"].get("new_traces", 0)) for e in obs.events())
-    print(f"{TAG} phase {label}: wall {wall!r} s, probed jit traces {traces}",
+    compiles = obs.value("jax.compiles") - c0
+    print(f"{TAG} phase {label}: wall {wall!r} s, compiles {compiles}",
           flush=True)
     return out
 

@@ -70,6 +70,7 @@ from repro.hetero.compose import (  # noqa: F401  (re-exported façade names)
     ComposePolicy, CompositionReport, compose,
 )
 from repro.sim.engine import SimPolicy  # noqa: F401  (re-exported façade name)
+from repro.transfer import fetch
 
 __all__ = [
     "Bucket", "LevelReq", "TaskReq", "SelectionPolicy",
@@ -222,19 +223,20 @@ class DesignTable:
 
         from repro.analysis import sanitize
         ops = corners_mod.as_corners(corners)
-        vecs = jnp.stack([c.to_vector() for c in configs])
-        with obs.span("api.characterize", probe=chz.characterize_batch,
-                      n_configs=len(configs), n_corners=len(ops)):
+        with obs.span("api.encode", n_configs=len(configs)):
+            vecs = jnp.stack([c.to_vector() for c in configs])
+        with obs.span("api.characterize", n_configs=len(configs),
+                      n_corners=len(ops)):
             if ops == (corners_mod.NOMINAL,):
                 out = sanitize.maybe_wrap(chz.characterize_batch)(vecs)
-                metrics = {k: np.asarray(v) for k, v in out.items()}
+                metrics = {k: fetch(v) for k, v in out.items()}
             else:
                 # characterize_corners sanitizes each per-corner dispatch
                 # itself (one jitted vmap per corner)
                 out = chz.characterize_corners(vecs, ops)
                 metrics = {}
                 for k, v in out.items():
-                    grid = np.asarray(v)                    # (N, C)
+                    grid = fetch(v)                         # (N, C)
                     metrics[k] = grid[:, 0]
                     for c, op in enumerate(ops):
                         metrics[f"{k}@{op.corner}"] = grid[:, c]

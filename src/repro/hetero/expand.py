@@ -30,6 +30,9 @@ from typing import Dict, Mapping, Tuple
 
 import numpy as np
 
+from repro import obs
+from repro.transfer import fetch
+
 
 def expansion_points(compose_policy) -> Tuple[Tuple[object, object], ...]:
     """The virtual-block schedule for a ComposePolicy: ``(op, margin)`` per
@@ -67,10 +70,11 @@ def expand_metrics(table, metrics: Mapping[str, np.ndarray],
             block = dict(metrics)            # base point: columns untouched
         else:
             if op not in per_op:
-                vecs = jnp.stack([c.to_vector()
-                                  for c in table.to_configs()])
+                configs = table.to_configs()
+                with obs.span("api.encode", n_configs=len(configs)):
+                    vecs = jnp.stack([c.to_vector() for c in configs])
                 out = chz.characterize_corners(vecs, (op,))
-                per_op[op] = {k: np.asarray(v)[:, 0] for k, v in out.items()}
+                per_op[op] = {k: fetch(v)[:, 0] for k, v in out.items()}
             char = per_op[op]
             block = {k: char.get(k, metrics[k]) for k in metrics}
         if margin is not None:

@@ -34,6 +34,7 @@ import numpy as np
 from repro import obs
 from repro.kernels import backend
 from repro.parallel.grid import shard2d, shard_leading
+from repro.transfer import fetch
 
 # DesignTable metric columns the scorer gathers from
 METRIC_COLS = ("area_um2", "bits", "p_leak_w", "p_refresh_w", "e_read_j",
@@ -252,9 +253,8 @@ def score_grid(metrics: Mapping[str, np.ndarray], idx: np.ndarray,
     slot_cap_bits = jnp.asarray(np.asarray(cap_bits), jnp.float32)
     slot_f_req_hz = jnp.asarray(np.asarray(f_req), jnp.float32)
     from repro.analysis import sanitize
-    with obs.span("hetero.score", probe=_score_jit,
-                  J=int(idx_dev.shape[0]), S=int(idx_dev.shape[1]),
-                  sharded=sharded):
+    with obs.span("hetero.score", J=int(idx_dev.shape[0]),
+                  S=int(idx_dev.shape[1]), sharded=sharded):
         if sharded:
             # shard_map composes badly with checkify's error plumbing; the
             # sanitizer covers the single-device path, which computes the same
@@ -264,8 +264,9 @@ def score_grid(metrics: Mapping[str, np.ndarray], idx: np.ndarray,
         else:
             out = sanitize.maybe_wrap(_score_jit)(
                 idx_dev, cols, slot_cap_bits, slot_f_req_hz)
+        scores = {k: fetch(v) for k, v in out.items()}
     _C_EVALS.inc()
-    return {k: np.asarray(v) for k, v in out.items()}
+    return scores
 
 
 def score_grid_corners(corner_metrics: Sequence[Mapping[str, np.ndarray]],
@@ -289,9 +290,9 @@ def score_grid_corners(corner_metrics: Sequence[Mapping[str, np.ndarray]],
     slot_cap_bits = jnp.asarray(np.asarray(cap_bits), jnp.float32)
     slot_f_req_hz = jnp.asarray(np.asarray(f_req), jnp.float32)
     from repro.analysis import sanitize
-    with obs.span("hetero.score", probe=_score_corners_jit,
-                  J=int(idx_dev.shape[0]), S=int(idx_dev.shape[1]),
-                  corners=len(corner_metrics), sharded=sharded):
+    with obs.span("hetero.score", J=int(idx_dev.shape[0]),
+                  S=int(idx_dev.shape[1]), corners=len(corner_metrics),
+                  sharded=sharded):
         if sharded:
             # same caveat as score_grid: shard_map composes badly with
             # checkify, and the single-device path computes identical values
@@ -300,5 +301,6 @@ def score_grid_corners(corner_metrics: Sequence[Mapping[str, np.ndarray]],
         else:
             out = sanitize.maybe_wrap(_score_corners_jit)(
                 idx_dev, cols, slot_cap_bits, slot_f_req_hz)
+        scores = {k: fetch(v) for k, v in out.items()}
     _C_EVALS.inc()
-    return {k: np.asarray(v) for k, v in out.items()}
+    return scores

@@ -16,9 +16,15 @@ from __future__ import annotations
 SPANS = {
     "api.compile": ("repro.api.Compiler.compile",
                     "single-macro characterization (one config, no vmap)"),
+    "api.encode": ("repro.api.DesignTable.from_configs, "
+                   "repro.hetero.expand.expand_metrics",
+                   "config-to-vector encoding of a design space (one eager "
+                   "program per config), before api.characterize and once "
+                   "per swept operating point inside hetero.expand"),
     "api.characterize": ("repro.api.DesignTable.from_configs",
                          "vmap characterization sweep over the config grid "
-                         "(nominal or corner-batched)"),
+                         "(nominal or corner-batched), up to its columns on "
+                         "the host"),
     "api.table_build": ("repro.api.DesignTable.build",
                         "table construction incl. the npz cache consult"),
     "api.explore": ("repro.api.explore",
@@ -33,13 +39,16 @@ SPANS = {
                       "operating-point expansion: per-(vdd point x refresh "
                       "margin) metric blocks for the vdd_sweep search axis"),
     "hetero.score": ("repro.hetero.system.score_grid[_corners]",
-                     "one batched composition-scoring dispatch "
-                     "(probe: the score jit — new_traces on first compile)"),
+                     "one batched composition-scoring dispatch, up to its "
+                     "columns on the host"),
+    "sim.prepare": ("repro.sim.engine.simulate_traces",
+                    "replay staging: gathering the compositions' table "
+                    "columns and the slot arrays, before sim.replay"),
     "sim.replay": ("repro.sim.engine.simulate_traces",
                    "batched trace replay over all phases of one call"),
     "sim.replay_phase": ("repro.sim.engine.simulate_traces",
-                         "one phase's vmapped scan dispatch "
-                         "(probe: the sim-grid jit)"),
+                         "one phase's vmapped scan dispatch, up to its "
+                         "metrics on the host"),
     "sim.rerank": ("repro.sim.rerank.simulate_report",
                    "simulate-then-rerank refinement incl. the sim cache "
                    "consult"),
@@ -57,6 +66,13 @@ SPANS = {
 
 # metric name -> (kind, what it counts/measures)
 METRICS = {
+    "jax.compiles": (
+        "counter", "programs compiled or loaded from the persistent cache, "
+        "any jit (a jax.monitoring listener, "
+        "repro.compile_cache.count_compiles); every span's compiles arg"),
+    "device.fetches": (
+        "counter", "arrays brought from the device to the host on the DSE "
+        "path (repro.transfer.fetch); every span's fetches arg"),
     "api.characterize_calls": (
         "counter", "vmap characterization sweeps executed "
         "(backs api.characterize_call_count — cache hits leave it flat)"),

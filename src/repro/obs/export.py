@@ -1,8 +1,9 @@
 """Trace exports: Chrome trace-event JSON (Perfetto-loadable) and JSONL.
 
 Chrome format (``.json``): one ``{"traceEvents": [...], "otherData": ...}``
-object — "X" (complete) events for spans with µs timestamps/durations, and
-"C" (counter) events for every registry counter at the trace end so the
+object — "X" (complete) events for spans with µs timestamps/durations
+(the span's ``depth``, ``id`` and ``parent`` ride in ``args``), and "C"
+(counter) events for every registry counter at the trace end so the
 counters render as tracks in Perfetto/``chrome://tracing``. The full
 metrics snapshot also rides verbatim in ``otherData["metrics"]``.
 
@@ -45,7 +46,8 @@ def chrome_trace(events: Optional[Sequence[Dict]] = None,
             "name": e["name"], "cat": e.get("cat", "repro"), "ph": "X",
             "ts": round(ts, 3), "dur": round(dur, 3),
             "pid": pid, "tid": e.get("tid", 0),
-            "args": dict(e.get("args", {}), depth=e.get("depth", 0)),
+            "args": dict(e.get("args", {}), depth=e.get("depth", 0),
+                         id=e.get("id"), parent=e.get("parent")),
         })
     for name, value in sorted((metrics.get("counters") or {}).items()):
         out.append({"name": name, "cat": "metrics", "ph": "C",
@@ -95,10 +97,12 @@ def read(path) -> Tuple[List[Dict[str, object]], Dict[str, object]]:
                 continue
             args = dict(e.get("args", {}))
             depth = args.pop("depth", 0)
+            ident, parent = args.pop("id", None), args.pop("parent", None)
             events.append({"name": e["name"], "cat": e.get("cat", "repro"),
                            "ph": "X", "ts": e.get("ts", 0.0),
                            "dur": e.get("dur", 0.0), "tid": e.get("tid", 0),
-                           "depth": depth, "args": args})
+                           "depth": depth, "id": ident, "parent": parent,
+                           "args": args})
         metrics = (payload.get("otherData") or {}).get("metrics") or {}
         return events, metrics
     events, metrics = [], {}

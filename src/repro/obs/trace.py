@@ -1,40 +1,54 @@
-"""Zero-dependency span tracer: wall-clock spans with jit-compile deltas.
+"""Zero-dependency span tracer: wall-clock spans with counter deltas.
 
-``span("hetero.score", probe=_score_jit, J=4096)`` is a context manager
-that records one trace event — name, category, start timestamp and
-duration [µs], nesting depth, thread id, and arbitrary JSON-serializable
-``args``. When tracing is *disabled* (the default) ``span()`` returns a
-shared no-op singleton: no allocation, no timestamp read, no lock — the
-instrumented hot paths pay one module-global boolean check.
+``span("hetero.score", J=4096)`` is a context manager that records one
+trace event — name, category, start timestamp and duration [µs], nesting
+depth, an ``id`` and the ``parent`` span's id, thread id, and arbitrary
+JSON-serializable ``args``. When tracing is *disabled* (the default)
+``span()`` returns a shared no-op singleton: no allocation, no timestamp
+read, no lock — the instrumented hot paths pay one module-global boolean
+check.
 
 Contract highlights (docs/OBSERVABILITY.md spells out the full catalog):
 
 - **exception safety**: a span body that raises still closes its event
   (the exception type lands in ``args["error"]``) and the exception
   propagates unchanged — tracing never swallows errors.
-- **compile-vs-execute split**: pass ``probe=<jitted fn>`` and the span
-  diffs the function's ``_cache_size()`` across its body; a nonzero delta
-  lands in ``args["new_traces"]``, so a trace shows exactly which call
-  paid a compilation. The probe is read, never wrapped — the jit cache
-  key and trace count of the probed function are untouched.
-- **nesting**: per-thread depth is recorded on every event, so exporters
-  can reconstruct the span tree without parent pointers.
+- **compile and fetch counts**: every span diffs the always-on counters
+  ``jax.compiles`` (programs compiled or loaded from the persistent cache,
+  fed by the listener ``repro.compile_cache.count_compiles`` registers)
+  and ``device.fetches`` (device arrays brought to the host,
+  ``repro.transfer.fetch``) across its body; a nonzero delta lands in
+  ``args["compiles"]`` / ``args["fetches"]``, so a trace shows exactly
+  which call paid a compilation or a transfer. Nothing is wrapped — jit
+  cache keys and trace counts are untouched.
+- **identity**: ``id`` is unique within the process; ``parent`` is the id
+  of the enclosing span on the same thread (None at the top), so the
+  spans of one query are the descendants of its outermost span.
+- **one clock with the device**: while a span is open it also holds a
+  ``jax.profiler.TraceAnnotation`` of the same name when jax is already
+  imported, so under the JAX profiler the program's spans sit in the
+  profiler's own trace beside the device ops.
 - **activation**: ``REPRO_TRACE=out.json`` in the environment enables
   tracing at import and writes the Chrome-trace file at process exit;
   ``enabled_scope(True)`` / ``enable()`` do the same programmatically
   (``repro.api.Compiler(telemetry=True)`` wraps its calls in a scope).
 
-Everything here is stdlib-only: no jax, no numpy — the tracer itself can
-never add a jit trace-cache entry (RC budgets) or touch numerics.
+Everything here is stdlib-only: no jax import, no numpy — the tracer
+itself can never add a jit trace-cache entry (RC budgets) or touch
+numerics; jax is only looked up in ``sys.modules``.
 """
 from __future__ import annotations
 
 import atexit
+import itertools
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
+
+from repro.obs import metrics
 
 # process epoch: event timestamps are µs since this module was imported
 _T0 = time.perf_counter()
@@ -44,6 +58,11 @@ _events: List[Dict[str, object]] = []
 _enabled = False
 _out_path: Optional[str] = None
 _tls = threading.local()
+_ids = itertools.count(1)
+
+# the always-on counters every enabled span diffs across its body
+COMPILES = metrics.counter("jax.compiles")
+FETCHES = metrics.counter("device.fetches")
 
 
 def enabled() -> bool:
@@ -79,18 +98,6 @@ def enabled_scope(on: bool = True):
         _enabled = prev
 
 
-def _probe_size(probe) -> Optional[int]:
-    """Trace-cache size of a jitted callable, via the same ``_cache_size()``
-    API the RC analyzer budgets; None when the probe has no such API."""
-    size = getattr(probe, "_cache_size", None)
-    if callable(size):
-        try:
-            return int(size())
-        except Exception:
-            return None
-    return None
-
-
 class _NullSpan:
     """Shared do-nothing span returned while tracing is disabled."""
     __slots__ = ()
@@ -108,15 +115,24 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` when jax is already
+    imported (never imported from here), else None."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    make = getattr(profiler, "TraceAnnotation", None)
+    return make(name) if make is not None else None
+
+
 class Span:
     """One live span (use via ``span(...)``, not directly)."""
-    __slots__ = ("name", "cat", "args", "_probe", "_t0", "_cache0", "_depth")
+    __slots__ = ("name", "cat", "args", "_t0", "_depth", "_id", "_parent",
+                 "_compiles0", "_fetches0", "_annot")
 
-    def __init__(self, name: str, cat: str, probe, args: Dict[str, object]):
+    def __init__(self, name: str, cat: str, args: Dict[str, object]):
         self.name = name
         self.cat = cat
         self.args = args
-        self._probe = probe
 
     def set(self, **kw):
         """Attach extra args mid-span (e.g. results known only at the end)."""
@@ -124,21 +140,33 @@ class Span:
         return self
 
     def __enter__(self):
-        self._depth = getattr(_tls, "depth", 0)
-        _tls.depth = self._depth + 1
-        self._cache0 = _probe_size(self._probe) \
-            if self._probe is not None else None
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self._depth = len(stack)
+        self._parent = stack[-1] if stack else None
+        self._id = next(_ids)
+        stack.append(self._id)
+        self._annot = _annotation(self.name)
+        if self._annot is not None:
+            self._annot.__enter__()
+        self._compiles0 = COMPILES.value
+        self._fetches0 = FETCHES.value
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
-        _tls.depth = self._depth
+        compiles = COMPILES.value - self._compiles0
+        fetches = FETCHES.value - self._fetches0
+        if self._annot is not None:
+            self._annot.__exit__(exc_type, exc, tb)
+        _tls.stack.pop()
         args = dict(self.args)
-        if self._cache0 is not None:
-            c1 = _probe_size(self._probe)
-            if c1 is not None and c1 != self._cache0:
-                args["new_traces"] = c1 - self._cache0
+        if compiles:
+            args["compiles"] = compiles
+        if fetches:
+            args["fetches"] = fetches
         if exc_type is not None:
             args["error"] = exc_type.__name__
         event = {
@@ -149,6 +177,8 @@ class Span:
             "dur": (t1 - self._t0) * 1e6,       # µs
             "tid": threading.get_ident() & 0xFFFFFFFF,
             "depth": self._depth,
+            "id": self._id,
+            "parent": self._parent,
             "args": args,
         }
         with _lock:
@@ -156,15 +186,11 @@ class Span:
         return False                             # never swallow the exception
 
 
-def span(name: str, cat: str = "repro", probe=None, **args):
-    """Context manager recording one trace event (no-op when disabled).
-
-    ``probe``: optional jitted callable whose ``_cache_size()`` delta across
-    the span body is reported as ``args["new_traces"]``.
-    """
+def span(name: str, cat: str = "repro", **args):
+    """Context manager recording one trace event (no-op when disabled)."""
     if not _enabled:
         return _NULL
-    return Span(name, cat, probe, args)
+    return Span(name, cat, args)
 
 
 def events() -> List[Dict[str, object]]:

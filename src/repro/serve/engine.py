@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.models import LM
 
 # per-step serving telemetry (repro.obs): dispatch counts always, wall-time
@@ -23,6 +23,8 @@ _C_DECODE = obs.counter("serve.decode_steps")
 _H_PREFILL_S = obs.histogram("serve.prefill_s")
 _H_DECODE_S = obs.histogram("serve.decode_step_s")
 _H_SAMPLE_S = obs.histogram("serve.sample_s")
+# the spans' ``compiles`` arg shows which generate() call compiled
+compile_cache.count_compiles()
 
 
 def make_prefill_step(cfg, max_seq: Optional[int] = None):
@@ -68,7 +70,7 @@ class Engine:
     def generate(self, batch: Dict[str, Any], steps: int, temperature=None,
                  seed=0):
         t0 = time.perf_counter()
-        with obs.span("serve.prefill", probe=self._prefill,
+        with obs.span("serve.prefill",
                       batch=int(jax.tree.leaves(batch)[0].shape[0])):
             cache, logits = self._prefill(self.params, batch)
         _C_PREFILL.inc()
@@ -93,7 +95,7 @@ class Engine:
             if cond is not None:
                 dec_batch["cond"] = cond
             t0 = time.perf_counter()
-            with obs.span("serve.decode_step", probe=self._decode, step=i):
+            with obs.span("serve.decode_step", step=i):
                 logits, cache = self._decode(self.params, cache, dec_batch)
             _C_DECODE.inc()
             _H_DECODE_S.observe(time.perf_counter() - t0)

@@ -48,6 +48,7 @@ from repro.hetero.system import slot_sum
 from repro.kernels import backend as _backend
 from repro.sim import refresh as refresh_mod
 from repro.sim.trace import Trace
+from repro.transfer import fetch
 
 # metric columns the engine gathers from a DesignTable, plus the axis-derived
 # "word_bits" column (``table["word_size"]``) the caller must add
@@ -307,9 +308,10 @@ def simulate_traces(cols: Mapping[str, np.ndarray], idx: np.ndarray,
         raise ValueError(f"trace slot counts {[t.n_slots for t in traces]} "
                          f"!= grid slot count {S}")
     t0 = traces[0]
-    params = _gather_params(cols, idx, t0.cap_bits, policy)
-    slot = {"cap_bits": jnp.asarray(t0.cap_bits, jnp.float32),
-            "lifetime_s": jnp.asarray(t0.lifetime_s, jnp.float32)}
+    with obs.span("sim.prepare", J=int(idx.shape[0]), S=int(S)):
+        params = _gather_params(cols, idx, t0.cap_bits, policy)
+        slot = {"cap_bits": jnp.asarray(t0.cap_bits, jnp.float32),
+                "lifetime_s": jnp.asarray(t0.lifetime_s, jnp.float32)}
     from repro.analysis import sanitize
     impl = sanitize.maybe_wrap(_backend.get_impl("sim_replay", backend))
 
@@ -328,11 +330,10 @@ def simulate_traces(cols: Mapping[str, np.ndarray], idx: np.ndarray,
                   jnp.asarray(tr.reads.T, jnp.float32),
                   jnp.asarray(tr.write_bits.T, jnp.float32),
                   jnp.asarray(tr.occupancy.T, jnp.float32))
-            with obs.span("sim.replay_phase", probe=_sim_grid_xla,
-                          phase=tr.phase):
+            with obs.span("sim.replay_phase", phase=tr.phase):
                 out = impl(params, slot, xs, consts)
-            per_phase[tr.phase] = _mask_sentinels(
-                {m: np.asarray(out[m], np.float64) for m in SIM_METRICS}, bad)
+                per_phase[tr.phase] = _mask_sentinels(
+                    {m: fetch(out[m], np.float64) for m in SIM_METRICS}, bad)
     _C_REPLAYS.inc()
 
     combined = _mask_sentinels(_combine_phases(per_phase), bad)

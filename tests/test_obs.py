@@ -4,8 +4,9 @@ instrumentation, and the benchmark perf-compare.
 The two non-negotiable guarantees proven here:
 
 - **telemetry off is free**: compose results are bit-identical with tracing
-  on vs off, and re-driving a warm jit site under an enabled scope adds
-  zero trace-cache entries (the probe is read, never wrapped).
+  on vs off (under the JAX profiler too, with every span annotated), and
+  re-driving a warm jit site under an enabled scope adds zero trace-cache
+  entries and records no ``compiles``.
 - **the catalog is the surface**: every span/metric name the pipeline emits
   is covered by ``repro.obs.catalog`` (and DC04 forces the docs to match).
 """
@@ -141,7 +142,8 @@ def test_export_roundtrip(tmp_path, suffix):
     assert len(events) == len(obs.events())
     for got, want in zip(events, obs.events()):
         assert set(got) == set(want)
-        for k in ("name", "cat", "ph", "tid", "depth", "args"):
+        for k in ("name", "cat", "ph", "tid", "depth", "id", "parent",
+                  "args"):
             assert got[k] == want[k]
         for k in ("ts", "dur"):                # writer rounds to 1 ns
             assert got[k] == pytest.approx(want[k], abs=1e-3)
@@ -194,10 +196,12 @@ def test_report_cli_module(tmp_path):
 
 def test_env_var_enables_and_atexit_flushes(tmp_path):
     path = tmp_path / "envtrace.json"
-    code = ("import repro.obs as obs\n"
+    code = ("import sys\n"
+            "import repro.obs as obs\n"
             "assert obs.enabled()\n"
             "with obs.span('t.env'):\n"
-            "    pass\n")
+            "    pass\n"
+            "assert 'jax' not in sys.modules\n")      # obs never imports jax
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
         env={**os.environ, "PYTHONPATH": str(SRC),
@@ -247,7 +251,147 @@ def test_no_retrace_under_enabled_scope(table):
     assert system._score_jit._cache_size() == n0
     score_spans = [e for e in obs.events() if e["name"] == "hetero.score"]
     assert score_spans
-    assert all("new_traces" not in e["args"] for e in score_spans)
+    assert all("compiles" not in e["args"] for e in score_spans)
+
+
+def test_bit_identical_and_no_retrace_under_profiler(table, tmp_path):
+    """With the JAX profiler recording, every span also opens a
+    TraceAnnotation: results stay bit-identical and no jit retraces."""
+    import jax
+
+    from repro.hetero import system
+
+    t = gainsight.TASKS[1]
+    ref = compose(table, t)
+    n0 = system._score_jit._cache_size()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.enabled_scope(True):
+            traced = compose(table, t)
+    assert system._score_jit._cache_size() == n0
+    assert obs.events()
+    assert all("compiles" not in e["args"] for e in obs.events())
+    assert traced.labels() == ref.labels()
+    for a, b in zip(ref.ranked, traced.ranked):
+        for k in a.metrics:
+            assert a.metrics[k] == b.metrics[k], k
+
+
+# ------------------------------------------- span identity and counter deltas
+def _tree_checks(events):
+    """``id`` unique; ``parent`` the enclosing span on the same thread,
+    which contains the child and sits one level up."""
+    by_id = {e["id"]: e for e in events}
+    assert len(by_id) == len(events)
+    for e in events:
+        if e["parent"] is None:
+            assert e["depth"] == 0
+            continue
+        p = by_id[e["parent"]]
+        assert p["tid"] == e["tid"] and p["depth"] == e["depth"] - 1
+        assert p["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1e-3
+    return by_id
+
+
+@pytest.mark.parametrize("suffix", [".json", ".jsonl"])
+def test_span_ids_tree_of_nested_compose(table, tmp_path, suffix):
+    configs = design_space()[:6]
+    cp = ComposePolicy(vdd_sweep=((1.0, 320.0),))
+    with obs.enabled_scope(True):
+        with obs.span("t.query"):
+            small = DesignTable.from_configs(configs)
+            compose(small, gainsight.TASKS[0], compose_policy=cp,
+                    refine="simulate")
+    events = obs.events()
+    by_id = _tree_checks(events)
+    (root,) = [e for e in events if e["name"] == "t.query"]
+
+    def ancestors(e):
+        while e["parent"] is not None:
+            e = by_id[e["parent"]]
+            yield e["name"]
+    # every span of the query descends from it
+    assert all(e is root or "t.query" in ancestors(e) for e in events)
+    names = {e["name"]: e for e in events}
+    # the table's encoding is a sibling opened before api.characterize
+    enc = [e for e in events if e["name"] == "api.encode"]
+    assert by_id[enc[0]["parent"]] is root
+    assert enc[0]["ts"] + enc[0]["dur"] <= names["api.characterize"]["ts"]
+    # each swept point's encoding nests inside hetero.expand
+    assert [list(ancestors(e))[0] for e in enc[1:]] == ["hetero.expand"]
+    # replay staging sits in sim.rerank, outside sim.replay
+    assert list(ancestors(names["sim.prepare"]))[0] == "sim.rerank"
+    assert list(ancestors(names["sim.replay_phase"]))[0] == "sim.replay"
+    path = tmp_path / f"trace{suffix}"
+    export.write(path, events, obs.snapshot())
+    back, _ = export.read(path)
+    assert [(e["id"], e["parent"]) for e in back] == \
+        [(e["id"], e["parent"]) for e in events]
+    _tree_checks(back)
+
+
+def test_per_corner_jit_compiles_show_in_span():
+    """A fresh per-corner jit compiles inside the enclosing span, and the
+    span's ``compiles`` says so; the warm call records none."""
+    import jax.numpy as jnp
+
+    from repro.core import characterize as chz
+    from repro.core.corners import OperatingPoint
+
+    vecs = jnp.stack([c.to_vector() for c in design_space()[:2]])
+    op = OperatingPoint(vdd=1.037, temp_k=311.5, corner="t_fresh")
+    with obs.enabled_scope(True):
+        with obs.span("t.cold"):
+            chz.characterize_corners(vecs, (op,))
+        with obs.span("t.warm"):
+            chz.characterize_corners(vecs, (op,))
+    ev = {e["name"]: e for e in obs.events()}
+    assert ev["t.cold"]["args"].get("compiles", 0) >= 1
+    assert "compiles" not in ev["t.warm"]["args"]
+
+
+def test_fetches_of_from_configs_equal_its_columns():
+    with obs.enabled_scope(True):
+        with obs.span("t.build"):
+            small = DesignTable.from_configs(design_space()[:3])
+    ev = {e["name"]: e for e in obs.events()}
+    n_cols = len(small.metric_names)
+    assert ev["t.build"]["args"]["fetches"] == n_cols
+    assert ev["api.characterize"]["args"]["fetches"] == n_cols
+    assert "fetches" not in ev["api.encode"]["args"]
+
+
+def test_spans_land_in_the_profiler_trace(table, tmp_path):
+    """Under jax.profiler every obs span is a host event of the same name;
+    aligned at one mark, the two clocks agree on every start."""
+    import jax
+
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.enabled_scope(True):
+            with obs.span("t.mark"):
+                pass
+            compose(table, gainsight.TASKS[0], refine="simulate")
+    events = obs.events()
+    (pb,) = tmp_path.rglob("*.xplane.pb")
+    host: dict = {}
+    names = {e["name"] for e in events}
+    for plane in jax.profiler.ProfileData.from_file(str(pb)).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for x in line.events:
+                if x.name in names:
+                    host.setdefault(x.name, []).append(x.start_ns)
+    by_name: dict = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e["ts"])
+    assert {n: len(v) for n, v in host.items()} == \
+        {n: len(v) for n, v in by_name.items()}
+    ns0 = host["t.mark"][0] - by_name["t.mark"][0] * 1e3
+    offsets = [x - (us * 1e3 + ns0)
+               for n in by_name
+               for x, us in zip(sorted(host[n]), sorted(by_name[n]))]
+    assert max(abs(o) for o in offsets) < 5e6, offsets          # 5 ms
 
 
 # ------------------------------------------------- end-to-end acceptance
@@ -325,9 +469,9 @@ def test_serve_engine_prefill_decode_spans():
     assert obs.value("serve.decode_steps") == d0 + 3
     hs = obs.snapshot()["histograms"]["serve.decode_step_s"]
     assert hs["count"] == h0 + 3 and hs["min"] > 0
-    # cold engine: the first generate() compiles, and the probe sees it
+    # cold engine: the first generate() compiles, and the span counts it
     prefill = next(e for e in obs.events() if e["name"] == "serve.prefill")
-    assert prefill["args"].get("new_traces", 0) >= 1
+    assert prefill["args"].get("compiles", 0) >= 1
     # sampling has its own span + histogram: decode_step time must no longer
     # absorb the sampling math or the host sync (the timing-attribution fix)
     assert names.count("serve.sample") == 3
