@@ -70,7 +70,7 @@ from repro.hetero.compose import (  # noqa: F401  (re-exported façade names)
     ComposePolicy, CompositionReport, compose,
 )
 from repro.sim.engine import SimPolicy  # noqa: F401  (re-exported façade name)
-from repro.transfer import fetch
+from repro.transfer import fetch, put
 
 __all__ = [
     "Bucket", "LevelReq", "TaskReq", "SelectionPolicy",
@@ -219,12 +219,23 @@ class DesignTable:
 
         ``corners``: operating points to batch over (None = nominal only;
         the nominal-only path is byte-identical to the pre-corner one)."""
-        import jax.numpy as jnp
-
         from repro.analysis import sanitize
         ops = corners_mod.as_corners(corners)
         with obs.span("api.encode", n_configs=len(configs)):
-            vecs = jnp.stack([c.to_vector() for c in configs])
+            axes = {
+                "mem_type": np.array([c.mem_type for c in configs]),
+                "word_size": np.array([c.word_size for c in configs],
+                                      np.int64),
+                "num_words": np.array([c.num_words for c in configs],
+                                      np.int64),
+                "banks": np.array([c.banks for c in configs], np.int64),
+                "level_shift": np.array([c.level_shift for c in configs],
+                                        bool),
+                "sa_current_mode": np.array(
+                    [c.sa_current_mode for c in configs], bool),
+                "mux": np.array([c.mux for c in configs], np.int64),
+            }
+            vecs = put(macro_mod.encode_axes(axes))
         with obs.span("api.characterize", n_configs=len(configs),
                       n_corners=len(ops)):
             if ops == (corners_mod.NOMINAL,):
@@ -241,16 +252,6 @@ class DesignTable:
                     for c, op in enumerate(ops):
                         metrics[f"{k}@{op.corner}"] = grid[:, c]
         _C_CHARACTERIZE.inc()
-        axes = {
-            "mem_type": np.array([c.mem_type for c in configs]),
-            "word_size": np.array([c.word_size for c in configs], np.int64),
-            "num_words": np.array([c.num_words for c in configs], np.int64),
-            "banks": np.array([c.banks for c in configs], np.int64),
-            "level_shift": np.array([c.level_shift for c in configs], bool),
-            "sa_current_mode": np.array([c.sa_current_mode for c in configs],
-                                        bool),
-            "mux": np.array([c.mux for c in configs], np.int64),
-        }
         return cls(axes, metrics, corners=ops)
 
     @classmethod
@@ -356,6 +357,11 @@ class DesignTable:
     @property
     def metric_names(self) -> Tuple[str, ...]:
         return tuple(self._metrics)
+
+    @property
+    def axes(self) -> Dict[str, np.ndarray]:
+        """Config axis columns only (``AXIS_NAMES``)."""
+        return dict(self._axes)
 
     @property
     def columns(self) -> Dict[str, np.ndarray]:

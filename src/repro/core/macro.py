@@ -12,6 +12,7 @@ import dataclasses
 from dataclasses import dataclass
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import bitcells, periphery, tech
 
@@ -34,16 +35,25 @@ class MacroConfig:
         return dataclasses.replace(self, **kw)
 
     def to_vector(self):
-        """Numeric encoding for the vmap'd characterization path."""
-        return jnp.asarray([
-            bitcells.MEM_TYPE[self.mem_type], self.word_size, self.num_words,
-            self.banks, int(self.level_shift), int(self.sa_current_mode),
-            self.mux,
-        ], jnp.float32)
+        """Numeric encoding of this one config on the device: the one row
+        ``encode_axes`` gives it."""
+        return jnp.asarray(
+            encode_axes({f: [getattr(self, f)] for f in VEC_FIELDS})[0])
 
 
 VEC_FIELDS = ("mem_type", "word_size", "num_words", "banks", "level_shift",
               "sa_current_mode", "mux")
+
+
+def encode_axes(axes) -> np.ndarray:
+    """Encode a config list's axis columns for the vmap'd characterization
+    path: ``axes`` maps each ``VEC_FIELDS`` name to a length-N column
+    (``mem_type`` as names); returns the (N, 7) float32 host array, fields
+    in ``VEC_FIELDS`` order, for one transfer to the device. Every field is
+    a small integer (bools as 0/1), so float32 holds it exactly."""
+    mem = [bitcells.MEM_TYPE[str(m)] for m in axes["mem_type"]]
+    cols = [mem] + [axes[f] for f in VEC_FIELDS[1:]]
+    return np.stack([np.asarray(c, np.float32) for c in cols], axis=1)
 
 
 def auto_mux(word_size, num_words):

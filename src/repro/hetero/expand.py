@@ -31,7 +31,8 @@ from typing import Dict, Mapping, Tuple
 import numpy as np
 
 from repro import obs
-from repro.transfer import fetch
+from repro.core.macro import encode_axes
+from repro.transfer import fetch, put
 
 
 def expansion_points(compose_policy) -> Tuple[Tuple[object, object], ...]:
@@ -55,24 +56,25 @@ def expand_metrics(table, metrics: Mapping[str, np.ndarray],
     in block order. Characterized columns come from one vmapped dispatch per
     swept operating point; columns the characterizer does not produce
     (axis-derived or user-added ones) are operating-point invariant and tile
-    through unchanged, as do the table's family labels.
+    through unchanged, as do the table's family labels. The table's axes are
+    encoded and put on the device once, at the first swept point, and every
+    swept point characterizes that same device array.
     """
-    import jax.numpy as jnp
-
     from repro.core import characterize as chz
 
     families = np.asarray(table.families)
     n_base = len(families)
     per_op: Dict[object, Dict[str, np.ndarray]] = {}
+    vecs = None                   # the table's encoding, put on first use
     blocks: list = []
     for op, margin in points:
         if op is None:
             block = dict(metrics)            # base point: columns untouched
         else:
             if op not in per_op:
-                configs = table.to_configs()
-                with obs.span("api.encode", n_configs=len(configs)):
-                    vecs = jnp.stack([c.to_vector() for c in configs])
+                if vecs is None:
+                    with obs.span("api.encode", n_configs=n_base):
+                        vecs = put(encode_axes(table.axes))
                 out = chz.characterize_corners(vecs, (op,))
                 per_op[op] = {k: fetch(v)[:, 0] for k, v in out.items()}
             char = per_op[op]

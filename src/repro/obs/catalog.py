@@ -18,9 +18,9 @@ SPANS = {
                     "single-macro characterization (one config, no vmap)"),
     "api.encode": ("repro.api.DesignTable.from_configs, "
                    "repro.hetero.expand.expand_metrics",
-                   "config-to-vector encoding of a design space (one eager "
-                   "program per config), before api.characterize and once "
-                   "per swept operating point inside hetero.expand"),
+                   "config-to-vector encoding of a design space (one (N, 7) "
+                   "host array and one device put), before api.characterize "
+                   "and once per expand call inside hetero.expand"),
     "api.characterize": ("repro.api.DesignTable.from_configs",
                          "vmap characterization sweep over the config grid "
                          "(nominal or corner-batched), up to its columns on "
@@ -73,6 +73,9 @@ METRICS = {
     "device.fetches": (
         "counter", "arrays brought from the device to the host on the DSE "
         "path (repro.transfer.fetch); every span's fetches arg"),
+    "device.puts": (
+        "counter", "host arrays sent to the device on the DSE path "
+        "(repro.transfer.put); every span's puts arg"),
     "api.characterize_calls": (
         "counter", "vmap characterization sweeps executed "
         "(backs api.characterize_call_count — cache hits leave it flat)"),

@@ -2,10 +2,11 @@
 
 ``python -m repro.obs report trace.json`` aggregates the span events —
 calls, total/mean/max wall time [ms], the programs compiled and the
-arrays fetched from the device inside them (the spans' ``compiles`` and
-``fetches`` args, summed), errors — and appends the counter / gauge /
-histogram snapshot. Works on both export formats. A span's counts include
-its children's, so counts of nested names overlap.
+arrays fetched from and put on the device inside them (the spans'
+``compiles``, ``fetches`` and ``puts`` args, summed), errors — and
+appends the counter / gauge / histogram snapshot. Works on both export
+formats. A span's counts include its children's, so counts of nested
+names overlap.
 """
 from __future__ import annotations
 
@@ -18,7 +19,7 @@ def aggregate(events: Sequence[Dict]) -> Dict[str, Dict[str, float]]:
     for e in events:
         row = agg.setdefault(e["name"], {
             "calls": 0, "total_ms": 0.0, "max_ms": 0.0,
-            "compiles": 0, "fetches": 0, "errors": 0})
+            "compiles": 0, "fetches": 0, "puts": 0, "errors": 0})
         dur_ms = float(e.get("dur", 0.0)) / 1e3
         row["calls"] += 1
         row["total_ms"] += dur_ms
@@ -26,6 +27,7 @@ def aggregate(events: Sequence[Dict]) -> Dict[str, Dict[str, float]]:
         args = e.get("args") or {}
         row["compiles"] += int(args.get("compiles", 0))
         row["fetches"] += int(args.get("fetches", 0))
+        row["puts"] += int(args.get("puts", 0))
         if "error" in args:
             row["errors"] += 1
     for row in agg.values():
@@ -47,12 +49,12 @@ def render(events: Optional[Sequence[Dict]] = None,
     if agg:
         lines.append(f"{'span':34s} {'calls':>6s} {'total_ms':>10s} "
                      f"{'mean_ms':>10s} {'max_ms':>10s} {'compiles':>8s} "
-                     f"{'fetches':>8s} {'errors':>6s}")
+                     f"{'fetches':>8s} {'puts':>6s} {'errors':>6s}")
         for name, r in sorted(agg.items(), key=lambda kv: -kv[1]["total_ms"]):
             lines.append(f"{name:34s} {r['calls']:6d} {r['total_ms']:10.3f} "
                          f"{r['mean_ms']:10.3f} {r['max_ms']:10.3f} "
                          f"{r['compiles']:8d} {r['fetches']:8d} "
-                         f"{r['errors']:6d}")
+                         f"{r['puts']:6d} {r['errors']:6d}")
     else:
         lines.append("no span events (tracing was off, or nothing ran)")
     counters = metrics.get("counters") or {}

@@ -13,13 +13,15 @@ Contract highlights (docs/OBSERVABILITY.md spells out the full catalog):
 - **exception safety**: a span body that raises still closes its event
   (the exception type lands in ``args["error"]``) and the exception
   propagates unchanged — tracing never swallows errors.
-- **compile and fetch counts**: every span diffs the always-on counters
-  ``jax.compiles`` (programs compiled or loaded from the persistent cache,
-  fed by the listener ``repro.compile_cache.count_compiles`` registers)
-  and ``device.fetches`` (device arrays brought to the host,
-  ``repro.transfer.fetch``) across its body; a nonzero delta lands in
-  ``args["compiles"]`` / ``args["fetches"]``, so a trace shows exactly
-  which call paid a compilation or a transfer. Nothing is wrapped — jit
+- **compile and transfer counts**: every span diffs the always-on
+  counters ``jax.compiles`` (programs compiled or loaded from the
+  persistent cache, fed by the listener
+  ``repro.compile_cache.count_compiles`` registers), ``device.fetches``
+  (device arrays brought to the host, ``repro.transfer.fetch``) and
+  ``device.puts`` (host arrays sent to the device, ``repro.transfer.put``)
+  across its body; a nonzero delta lands in ``args["compiles"]`` /
+  ``args["fetches"]`` / ``args["puts"]``, so a trace shows exactly which
+  call paid a compilation or a transfer. Nothing is wrapped — jit
   cache keys and trace counts are untouched.
 - **identity**: ``id`` is unique within the process; ``parent`` is the id
   of the enclosing span on the same thread (None at the top), so the
@@ -63,6 +65,7 @@ _ids = itertools.count(1)
 # the always-on counters every enabled span diffs across its body
 COMPILES = metrics.counter("jax.compiles")
 FETCHES = metrics.counter("device.fetches")
+PUTS = metrics.counter("device.puts")
 
 
 def enabled() -> bool:
@@ -127,7 +130,7 @@ def _annotation(name: str):
 class Span:
     """One live span (use via ``span(...)``, not directly)."""
     __slots__ = ("name", "cat", "args", "_t0", "_depth", "_id", "_parent",
-                 "_compiles0", "_fetches0", "_annot")
+                 "_compiles0", "_fetches0", "_puts0", "_annot")
 
     def __init__(self, name: str, cat: str, args: Dict[str, object]):
         self.name = name
@@ -152,6 +155,7 @@ class Span:
             self._annot.__enter__()
         self._compiles0 = COMPILES.value
         self._fetches0 = FETCHES.value
+        self._puts0 = PUTS.value
         self._t0 = time.perf_counter()
         return self
 
@@ -159,6 +163,7 @@ class Span:
         t1 = time.perf_counter()
         compiles = COMPILES.value - self._compiles0
         fetches = FETCHES.value - self._fetches0
+        puts = PUTS.value - self._puts0
         if self._annot is not None:
             self._annot.__exit__(exc_type, exc, tb)
         _tls.stack.pop()
@@ -167,6 +172,8 @@ class Span:
             args["compiles"] = compiles
         if fetches:
             args["fetches"] = fetches
+        if puts:
+            args["puts"] = puts
         if exc_type is not None:
             args["error"] = exc_type.__name__
         event = {

@@ -131,6 +131,71 @@ def test_table_filter_callable_and_columns():
     assert "f_op_hz" in table and "word_size" in table
 
 
+def _edge_configs():
+    """Every bitcell, banked, every mux mode, both booleans on."""
+    from repro.core import bitcells
+    out = [MacroConfig(mem_type=mt, word_size=64, num_words=256)
+           for mt in bitcells.MEM_TYPE]
+    out += [MacroConfig(mem_type=mt, word_size=16, num_words=512, banks=4,
+                        mux=mux, level_shift=True, sa_current_mode=True)
+            for mt in ("sram6t", "gc_ossi") for mux in (0, 2, 8)]
+    return out
+
+
+def test_encode_axes_equals_the_per_config_encoding():
+    """One host array of the axis columns holds, bit for bit, what the
+    per-config encoding of the same list stacks (and what the literal
+    per-field list gives)."""
+    import jax.numpy as jnp
+
+    from repro.core import bitcells, macro
+    cfgs = list(api.design_space()) + _edge_configs()
+    table_axes = {
+        "mem_type": np.array([c.mem_type for c in cfgs]),
+        **{f: np.array([getattr(c, f) for c in cfgs])
+           for f in macro.VEC_FIELDS[1:]}}
+    enc = macro.encode_axes(table_axes)
+    assert enc.dtype == np.float32 and enc.shape == (len(cfgs), 7)
+    per_config = np.stack([np.asarray(c.to_vector()) for c in cfgs])
+    literal = np.stack([np.asarray(jnp.asarray(
+        [bitcells.MEM_TYPE[c.mem_type], c.word_size, c.num_words, c.banks,
+         int(c.level_shift), int(c.sa_current_mode), c.mux], jnp.float32))
+        for c in cfgs])
+    assert per_config.dtype == np.float32
+    assert np.array_equal(enc, per_config)
+    assert np.array_equal(enc, literal)
+    # the table's own axis columns encode the same way
+    table_enc = macro.encode_axes(DesignTable(table_axes, {}).axes)
+    assert np.array_equal(table_enc, enc)
+
+
+@pytest.mark.parametrize("corners", [None, ("nominal", "hot")])
+def test_from_configs_columns_equal_the_per_config_stack(corners):
+    """A table encoded as one host array characterizes to exactly the
+    columns of the per-config ``to_vector`` stack."""
+    import jax.numpy as jnp
+
+    from repro.core import characterize as chz
+    from repro.core import corners as corners_mod
+    cfgs = small_space()[:6] + _edge_configs()[:4]
+    table = DesignTable.from_configs(cfgs, corners=corners)
+    vecs = jnp.stack([c.to_vector() for c in cfgs])
+    if corners is None:
+        ref = {k: np.asarray(v) for k, v in
+               chz.characterize_batch(vecs).items()}
+    else:
+        ops = corners_mod.as_corners(corners)
+        ref = {}
+        for k, v in chz.characterize_corners(vecs, ops).items():
+            grid = np.asarray(v)
+            ref[k] = grid[:, 0]
+            ref.update({f"{k}@{op.corner}": grid[:, c]
+                        for c, op in enumerate(ops)})
+    assert set(table.metric_names) == set(ref)
+    for k, v in ref.items():
+        np.testing.assert_array_equal(table.metrics[k], v, err_msg=k)
+
+
 # ------------------------------------------------------------------ explore
 def test_explore_reproduces_table2_and_hits_cache(tmp_path):
     report = explore(tasks=gainsight.TASKS, cache=tmp_path)

@@ -361,6 +361,32 @@ def test_fetches_of_from_configs_equal_its_columns():
     assert "fetches" not in ev["api.encode"]["args"]
 
 
+def test_encode_of_from_configs_is_one_put():
+    """The whole design space is encoded on the host and sent to the
+    device as one array: one span, one put, no program, no fetch."""
+    with obs.enabled_scope(True):
+        DesignTable.from_configs(design_space())
+    (enc,) = [e for e in obs.events() if e["name"] == "api.encode"]
+    assert enc["args"] == {"n_configs": 120, "puts": 1}
+
+
+def test_expand_encodes_once_for_every_swept_point():
+    """Two swept points share one encoding of the table, inside
+    hetero.expand."""
+    small = DesignTable.from_configs(design_space()[:6])
+    cp = ComposePolicy(vdd_sweep=((1.0, 320.0), (1.2, 233.0)))
+    with obs.enabled_scope(True):
+        compose(small, gainsight.TASKS[0], compose_policy=cp)
+    events = obs.events()
+    by_id = {e["id"]: e for e in events}
+    (expand,) = [e for e in events if e["name"] == "hetero.expand"]
+    assert expand["args"]["n_points"] == 3
+    (enc,) = [e for e in events if e["name"] == "api.encode"]
+    assert by_id[enc["parent"]] is expand
+    assert enc["args"]["n_configs"] == 6 and enc["args"]["puts"] == 1
+    assert expand["args"]["puts"] == 1
+
+
 def test_spans_land_in_the_profiler_trace(table, tmp_path):
     """Under jax.profiler every obs span is a host event of the same name;
     aligned at one mark, the two clocks agree on every start."""
