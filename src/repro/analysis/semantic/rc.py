@@ -128,6 +128,9 @@ SITES: Tuple[RcSite, ...] = (
     # two batch sizes x one stacked-corner shape
     RcSite("characterize_corners_batch", "src/repro/core/characterize.py",
            "characterize_corners_batch", 2),
+    # two batch sizes x one corner count
+    RcSite("stack_corners", "src/repro/core/characterize.py",
+           "_stack_corners", 2),
     # full bitcell menu + a 3-cell subset
     RcSite("retention_time_batch", "src/repro/core/retention.py",
            "retention_time_batch", 2),

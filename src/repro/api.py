@@ -70,7 +70,7 @@ from repro.hetero.compose import (  # noqa: F401  (re-exported façade names)
     ComposePolicy, CompositionReport, compose,
 )
 from repro.sim.engine import SimPolicy  # noqa: F401  (re-exported façade name)
-from repro.transfer import fetch, put
+from repro.transfer import fetch_tree, put
 
 __all__ = [
     "Bucket", "LevelReq", "TaskReq", "SelectionPolicy",
@@ -240,14 +240,13 @@ class DesignTable:
                       n_corners=len(ops)):
             if ops == (corners_mod.NOMINAL,):
                 out = sanitize.maybe_wrap(chz.characterize_batch)(vecs)
-                metrics = {k: fetch(v) for k, v in out.items()}
+                metrics = fetch_tree(out)
             else:
                 # characterize_corners sanitizes each per-corner dispatch
                 # itself (one jitted vmap per corner)
-                out = chz.characterize_corners(vecs, ops)
+                grids = fetch_tree(chz.characterize_corners(vecs, ops))
                 metrics = {}
-                for k, v in out.items():
-                    grid = fetch(v)                         # (N, C)
+                for k, grid in grids.items():               # (N, C)
                     metrics[k] = grid[:, 0]
                     for c, op in enumerate(ops):
                         metrics[f"{k}@{op.corner}"] = grid[:, c]

@@ -192,15 +192,22 @@ def _characterize_vmap_jit(tp):
     return jax.jit(jax.vmap(functools.partial(characterize, tp=tp)))
 
 
+# the per-corner output dicts joined into (N, C) columns in one program:
+# a stack moves bits and computes nothing, so each column stays bit-exact
+# with its corner's own dispatch
+_stack_corners = jax.jit(lambda per_corner: {
+    k: jnp.stack([out[k] for out in per_corner], axis=1)
+    for k in per_corner[0]})
+
+
 def characterize_corners(vecs, ops):
     """Characterize config vectors ``vecs`` (N, 7) at every operating point
     of ``ops`` (OperatingPoints / corner names), one vmapped dispatch per
     corner so each corner column is bit-exact with the scalar
-    ``characterize_config`` path at that corner.
+    ``characterize_config`` path at that corner. Every corner's dispatch is
+    issued before the one program that stacks them.
 
     Returns a dict of (N, C) jnp arrays, corner order = ``ops`` order."""
-    import jax.numpy as jnp
-
     from repro.analysis import sanitize
     per_corner = []
     for o in ops:
@@ -208,8 +215,7 @@ def characterize_corners(vecs, ops):
         fn = characterize_batch if tp == corners.NOMINAL_TECH \
             else _characterize_vmap_jit(tp)
         per_corner.append(sanitize.maybe_wrap(fn)(vecs))
-    return {k: jnp.stack([out[k] for out in per_corner], axis=1)
-            for k in per_corner[0]}
+    return _stack_corners(per_corner)
 
 
 # one jitted closure per corner: tp stays a python-float NamedTuple closed

@@ -32,7 +32,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.macro import encode_axes
-from repro.transfer import fetch, put
+from repro.transfer import fetch_tree, put
 
 
 def expansion_points(compose_policy) -> Tuple[Tuple[object, object], ...]:
@@ -54,29 +54,30 @@ def expand_metrics(table, metrics: Mapping[str, np.ndarray],
     ``metrics`` is the (n_base,)-column dict the compose pass would
     otherwise rank on; the return columns have ``len(points) * n_base`` rows
     in block order. Characterized columns come from one vmapped dispatch per
-    swept operating point; columns the characterizer does not produce
+    distinct swept operating point, all issued before one fetch of every
+    point's columns; columns the characterizer does not produce
     (axis-derived or user-added ones) are operating-point invariant and tile
     through unchanged, as do the table's family labels. The table's axes are
-    encoded and put on the device once, at the first swept point, and every
-    swept point characterizes that same device array.
+    encoded and put on the device once, and every swept point characterizes
+    that same device array.
     """
     from repro.core import characterize as chz
 
     families = np.asarray(table.families)
     n_base = len(families)
+    swept = list(dict.fromkeys(op for op, _ in points if op is not None))
     per_op: Dict[object, Dict[str, np.ndarray]] = {}
-    vecs = None                   # the table's encoding, put on first use
+    if swept:
+        with obs.span("api.encode", n_configs=n_base):
+            vecs = put(encode_axes(table.axes))
+        grids = fetch_tree(chz.characterize_corners(vecs, swept))
+        per_op = {op: {k: g[:, c] for k, g in grids.items()}
+                  for c, op in enumerate(swept)}
     blocks: list = []
     for op, margin in points:
         if op is None:
             block = dict(metrics)            # base point: columns untouched
         else:
-            if op not in per_op:
-                if vecs is None:
-                    with obs.span("api.encode", n_configs=n_base):
-                        vecs = put(encode_axes(table.axes))
-                out = chz.characterize_corners(vecs, (op,))
-                per_op[op] = {k: fetch(v)[:, 0] for k, v in out.items()}
             char = per_op[op]
             block = {k: char.get(k, metrics[k]) for k in metrics}
         if margin is not None:

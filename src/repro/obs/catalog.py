@@ -71,8 +71,9 @@ METRICS = {
         "any jit (a jax.monitoring listener, "
         "repro.compile_cache.count_compiles); every span's compiles arg"),
     "device.fetches": (
-        "counter", "arrays brought from the device to the host on the DSE "
-        "path (repro.transfer.fetch); every span's fetches arg"),
+        "counter", "device-to-host transfers on the DSE path "
+        "(repro.transfer.fetch, one array; fetch_tree, a whole pytree); "
+        "every span's fetches arg"),
     "device.puts": (
         "counter", "host arrays sent to the device on the DSE path "
         "(repro.transfer.put); every span's puts arg"),

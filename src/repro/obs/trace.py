@@ -17,7 +17,7 @@ Contract highlights (docs/OBSERVABILITY.md spells out the full catalog):
   counters ``jax.compiles`` (programs compiled or loaded from the
   persistent cache, fed by the listener
   ``repro.compile_cache.count_compiles`` registers), ``device.fetches``
-  (device arrays brought to the host, ``repro.transfer.fetch``) and
+  (device-to-host transfers, ``repro.transfer.fetch`` / ``fetch_tree``) and
   ``device.puts`` (host arrays sent to the device, ``repro.transfer.put``)
   across its body; a nonzero delta lands in ``args["compiles"]`` /
   ``args["fetches"]`` / ``args["puts"]``, so a trace shows exactly which

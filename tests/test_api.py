@@ -196,6 +196,102 @@ def test_from_configs_columns_equal_the_per_config_stack(corners):
         np.testing.assert_array_equal(table.metrics[k], v, err_msg=k)
 
 
+def _per_column(out):
+    return {k: np.asarray(v) for k, v in out.items()}
+
+
+def _assert_bit_equal(got, ref):
+    assert set(got) == set(ref)
+    for k, v in ref.items():
+        assert got[k].dtype == v.dtype, k
+        assert np.array_equal(got[k], v), k
+
+
+@pytest.mark.parametrize("corners", [None, ("nominal", "hot", "cold")])
+def test_from_configs_one_fetch_is_bit_identical_to_per_column(corners):
+    """The whole grid's columns, joined on the device and fetched in one
+    transfer, are the very bits of a per-column ``np.asarray`` of each
+    corner's own characterize dispatch."""
+    import jax.numpy as jnp
+
+    from repro.core import characterize as chz
+    from repro.core import corners as corners_mod
+    from repro.core.macro import encode_axes
+    table = DesignTable.from_configs(api.design_space(), corners=corners)
+    vecs = jnp.asarray(encode_axes(table.axes))
+    if corners is None:
+        ref = _per_column(chz.characterize_batch(vecs))
+    else:
+        ref = {}
+        for op in corners_mod.as_corners(corners):
+            tp = corners_mod.resolve(op)
+            fn = chz.characterize_batch if tp == corners_mod.NOMINAL_TECH \
+                else chz._characterize_vmap_jit(tp)
+            cols = _per_column(fn(vecs))
+            ref.update({f"{k}@{op.corner}": v for k, v in cols.items()})
+            if op.corner == "nominal":
+                ref.update(cols)
+        stacked = _per_column(chz.characterize_corners(
+            vecs, corners_mod.as_corners(corners)))
+        for c, op in enumerate(corners_mod.as_corners(corners)):
+            for k, grid in stacked.items():
+                assert np.array_equal(grid[:, c], ref[f"{k}@{op.corner}"])
+    _assert_bit_equal(table.metrics, ref)
+
+
+def test_fetch_tree_mixed_shapes_one_fetch_per_call():
+    """Leaves of shape (N,) and (N, C) come back exact, in their shapes,
+    for one ``device.fetches`` increment per call."""
+    import jax.numpy as jnp
+
+    from repro import obs
+    from repro.transfer import fetch_tree
+    rng = np.random.default_rng(3)
+    host = {"a": rng.standard_normal(5).astype(np.float32),
+            "b": rng.standard_normal((5, 3)).astype(np.float32),
+            "c": [rng.standard_normal((2, 4)).astype(np.float32)]}
+    tree = {"a": jnp.asarray(host["a"]), "b": jnp.asarray(host["b"]),
+            "c": [jnp.asarray(host["c"][0])]}
+    before = obs.snapshot()["counters"].get("device.fetches", 0)
+    back = fetch_tree(tree)
+    back2 = fetch_tree(tree)
+    assert obs.snapshot()["counters"]["device.fetches"] - before == 2
+    for got in (back, back2):
+        assert isinstance(got["c"], list)
+        _assert_bit_equal({"a": got["a"], "b": got["b"], "c": got["c"][0]},
+                          {"a": host["a"], "b": host["b"],
+                           "c": host["c"][0]})
+
+
+def test_fetch_tree_rejects_mixed_dtypes():
+    import jax.numpy as jnp
+
+    from repro.transfer import fetch_tree
+    with pytest.raises(TypeError, match="one dtype"):
+        fetch_tree({"x": jnp.zeros(3, jnp.float32),
+                    "n": jnp.zeros(3, jnp.int32)})
+
+
+def test_fetch_tree_same_structure_compiles_once():
+    """A second call with the same leaf shapes reuses the join program:
+    its span records no compile."""
+    import jax.numpy as jnp
+
+    from repro import obs
+    from repro.transfer import fetch_tree
+    tree = {"p": jnp.arange(7, dtype=jnp.float32),
+            "q": jnp.ones((7, 2), jnp.float32)}
+    obs.clear()
+    with obs.enabled_scope(True):
+        with obs.span("t.first"):
+            fetch_tree(tree)
+        with obs.span("t.second"):
+            fetch_tree(tree)
+    ev = {e["name"]: e for e in obs.events()}
+    assert ev["t.first"]["args"]["fetches"] == 1
+    assert ev["t.second"]["args"] == {"fetches": 1}
+
+
 # ------------------------------------------------------------------ explore
 def test_explore_reproduces_table2_and_hits_cache(tmp_path):
     report = explore(tasks=gainsight.TASKS, cache=tmp_path)

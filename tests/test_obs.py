@@ -350,15 +350,34 @@ def test_per_corner_jit_compiles_show_in_span():
     assert "compiles" not in ev["t.warm"]["args"]
 
 
-def test_fetches_of_from_configs_equal_its_columns():
+@pytest.mark.parametrize("corners", [None, ("nominal", "hot")])
+def test_from_configs_fetches_once_per_characterize_call(corners):
+    """Every metric column of a characterize call, at every corner, comes
+    to the host in one transfer."""
     with obs.enabled_scope(True):
         with obs.span("t.build"):
-            small = DesignTable.from_configs(design_space()[:3])
+            small = DesignTable.from_configs(design_space()[:3],
+                                             corners=corners)
     ev = {e["name"]: e for e in obs.events()}
-    n_cols = len(small.metric_names)
-    assert ev["t.build"]["args"]["fetches"] == n_cols
-    assert ev["api.characterize"]["args"]["fetches"] == n_cols
+    assert len(small.metric_names) > 1
+    assert ev["t.build"]["args"]["fetches"] == 1
+    assert ev["api.characterize"]["args"]["fetches"] == 1
     assert "fetches" not in ev["api.encode"]["args"]
+
+
+def test_expand_over_three_points_fetches_once():
+    """Three swept operating points characterize in three dispatches and
+    come to the host in one transfer, inside hetero.expand."""
+    small = DesignTable.from_configs(design_space()[:6])
+    cp = ComposePolicy(vdd_sweep=((1.2, 233.0), (0.9, 300.0),
+                                  (1.1, 358.0)),
+                       refresh_margin_sweep=(0.8,))
+    with obs.enabled_scope(True):
+        compose(small, gainsight.TASKS[0], compose_policy=cp)
+    (expand,) = [e for e in obs.events() if e["name"] == "hetero.expand"]
+    assert expand["args"]["n_points"] == 8
+    assert expand["args"]["fetches"] == 1
+    assert expand["args"]["puts"] == 1
 
 
 def test_encode_of_from_configs_is_one_put():

@@ -66,6 +66,19 @@ def test_characterize_corner_compiles(one_chip):
     _compile(fn, _f32(120, 7), sharding=one_chip)
 
 
+def test_corner_stack_and_one_fetch_join_compile(one_chip):
+    """The (N, C) stack of three swept points' 19 columns, and the join
+    that brings them to the host in one transfer, compile as pure data
+    moves: no fusion computes anything."""
+    from repro.transfer import _join
+    per_corner = [{f"m{i}": _f32(120) for i in range(19)}] * 3
+    _compile(characterize._stack_corners, per_corner, sharding=one_chip)
+    hlo = _compile(_join, [_f32(120, 3)] * 19, sharding=one_chip)
+    assert "concatenate" in hlo
+    for op in ("add(", "multiply(", "divide("):
+        assert op not in hlo, op
+
+
 def test_score_grid_compiles(one_chip):
     J, S = 20_000, 5
     _compile(_score_jit, jax.ShapeDtypeStruct((J, S), jnp.int32),

@@ -103,6 +103,36 @@ def test_block0_passthrough_is_bit_identical(table):
         np.asarray(table.metrics["retention_s"]))
 
 
+def test_expand_one_fetch_is_bit_identical_to_per_column(table):
+    """Over the benchmark's sweep (3 vdd points x 2 refresh margins), every
+    expanded column is the very bits, and dtype, of a per-column
+    ``np.asarray`` of each swept point's own characterize dispatch."""
+    import jax.numpy as jnp
+
+    from repro.core import characterize as chz
+    from repro.core.macro import encode_axes
+    cp = ComposePolicy(vdd_sweep=((1.2, 233.0), (0.9, 300.0), (1.1, 358.0)),
+                       refresh_margin_sweep=(0.8, 0.5))
+    points = expand.expansion_points(cp)
+    assert len(points) == 12
+    metrics, _ = expand.expand_metrics(table, table.metrics, points)
+    vecs = jnp.asarray(encode_axes(table.axes))
+    blocks = []
+    for op, margin in points:
+        block = dict(table.metrics)
+        if op is not None:
+            tp = corners.resolve(corners.as_operating_point(op))
+            out = chz._characterize_vmap_jit(tp)(vecs)
+            block.update({k: np.asarray(v) for k, v in out.items()})
+        if margin is not None:
+            block["p_refresh_w"] = block["p_refresh_w"] / float(margin)
+        blocks.append(block)
+    for k in table.metrics:
+        ref = np.concatenate([b[k] for b in blocks])
+        assert metrics[k].dtype == ref.dtype, k
+        assert np.array_equal(metrics[k], ref), k
+
+
 def test_to_base_preserves_sentinels():
     idx = np.array([[0, 5, -1], [7, 3, 9]])
     out = expand.to_base(idx, 4)
