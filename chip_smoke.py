@@ -11,7 +11,8 @@ in one process:
      vdd-sweep golden; the full (vdd x refresh-margin) sweep against its
      golden winners
   3  N-level branch-and-bound against exhaustive search
-  4  trace replay at the benchmark's full size against the interpret oracle
+  4  trace replay of a seeded 50,000-composition grid against the
+     interpret oracle
   5  the Pallas retention kernel, natively, against its jnp reference
 
 Usage::
@@ -36,7 +37,7 @@ import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent
-sys.path[:0] = [str(REPO / "src"), str(REPO / "scripts"), str(REPO)]
+sys.path[:0] = [str(REPO / "src"), str(REPO / "scripts")]
 
 TAG = "[smoke, not a benchmark]"
 
@@ -65,6 +66,34 @@ def run_phase(label: str, fn, *args):
     print(f"{TAG} phase {label}: wall {wall!r} s, compiles {compiles}",
           flush=True)
     return out
+
+
+# the replayed task: two levels of two buckets each (S = 4 slots)
+REPLAY_TASK_LEVELS = (
+    ("L1", 1 << 20, ((0.6, 1.2e9, 2e-6), (0.4, 5e8, 1e-4))),
+    ("L2", 64 << 20, ((0.5, 1e9, 1e-3), (0.5, 2e9, 3e-6))),
+)
+REPLAY_PHASES = ("prefill", "decode")
+
+
+def replay_grid(table, J: int, bins: int, seed: int = 0):
+    """The synthetic replay problem: ``(cols, idx (J, S), traces)`` with
+    uniform random table rows per slot — the same gather + scan cost profile
+    as a real top-K re-rank, but with a controllable J."""
+    import numpy as np
+
+    from repro.core.select import Bucket, LevelReq, TaskReq
+    from repro.sim import task_traces
+    from repro.sim.rerank import sim_cols
+
+    task = TaskReq("bench", "bench", {
+        name: LevelReq(name, cap, tuple(Bucket(*b) for b in buckets))
+        for name, cap, buckets in REPLAY_TASK_LEVELS})
+    traces = task_traces(task, phases=REPLAY_PHASES, n_bins=bins)
+    S = sum(len(b) for _, _, b in REPLAY_TASK_LEVELS)
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, len(table), size=(J, S)).astype(np.int32)
+    return sim_cols(table), idx, traces
 
 
 # ---------------------------------------------------------------------------
@@ -124,11 +153,11 @@ def phase1_characterize(golden):
 
 
 def phase2_table2(vdd_golden):
-    from benchmarks.vdd_sweep import FULL_MARGINS, FULL_VDDS
     from repro.api import compose, explore
     from repro.core import gainsight
     from repro.hetero import ComposePolicy
-    from update_golden import full_sweep_tasks, golden_diff, vdd_tasks
+    from update_golden import (FULL_MARGINS, FULL_VDDS, full_sweep_tasks,
+                               golden_diff, vdd_tasks)
 
     expected = gainsight.TABLE2_EXPECTED
     report = explore(tasks=gainsight.TASKS)
@@ -168,7 +197,6 @@ def phase3_nlevel(table):
     from repro.core.gainsight import nlevel_task
     from repro.hetero import ComposePolicy
 
-    # the kwargs of the benchmark harness (benchmarks/run.py)
     kw = dict(objective="power", candidate_mode="all_feasible",
               max_candidates_per_bucket=16)
     ex = compose(table, nlevel_task(4), compose_policy=ComposePolicy(
@@ -188,11 +216,9 @@ def phase3_nlevel(table):
 def phase4_replay(table):
     import numpy as np
 
-    from benchmarks.sim_replay import replay_grid
     from repro.sim import simulate_traces
     from repro.sim.engine import SIM_METRICS
 
-    # the full size of benchmarks/sim_replay.py
     cols, idx, traces = replay_grid(table, J=50_000, bins=32)
     require(idx.shape == (50_000, 4), f"replay grid {idx.shape}")
     out = simulate_traces(cols, idx, traces, backend="xla")

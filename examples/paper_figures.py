@@ -1,11 +1,12 @@
-"""Benchmarks for every paper table/figure (Figs 7-11, Tables 1-2), driven
+"""The paper's figure and table data (Figs 7-11, Table 2), printed row by row
 through the ``repro.api`` façade (Compiler / DesignTable / explore).
 
-Each function returns (rows, derived) where rows are printable dicts and
-`derived` is a one-line summary of the claim being reproduced.
-"""
-from __future__ import annotations
+Each figure function returns ``(rows, derived)``: the printable rows and a
+one-line summary of the claim being reproduced, which
+``tests/test_core_compiler.py`` asserts. Run::
 
+    pip install -e . && python examples/paper_figures.py
+"""
 import numpy as np
 
 from repro.api import Compiler, DesignTable, MacroConfig, explore
@@ -134,3 +135,16 @@ def fig11_shmoo():
 
 ALL = [fig7_area, fig8_speed_power, fig9_retention, fig10_requirements,
        table2_optimal, fig11_shmoo]
+
+
+def main():
+    for fn in ALL:
+        rows, derived = fn()
+        print(f"== {fn.__name__}: {fn.__doc__.splitlines()[0]}")
+        for row in rows:
+            print("  " + ", ".join(f"{k}={v}" for k, v in row.items()))
+        print(f"  -> {derived}\n")
+
+
+if __name__ == "__main__":
+    main()

@@ -25,7 +25,7 @@ from pathlib import Path
 from typing import Dict, List, Tuple
 
 REPO = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(REPO / "src"), str(REPO)]
+sys.path.insert(0, str(REPO / "src"))
 
 GOLDEN_PATH = REPO / "tests" / "golden" / "table2.json"
 NLEVEL_PATH = REPO / "tests" / "golden" / "table2_nlevel.json"
@@ -36,6 +36,10 @@ VDD_PATH = REPO / "tests" / "golden" / "table2_vdd.json"
 # take over retention-marginal L1/L2 buckets — the co-optimization axis must
 # keep flipping exactly these Table-2 winners (the MCAIMem effect)
 VDD_SWEEP_POINT = (1.2, 233.0)
+# the full sweep, the same points as the chip benchmark's table2_vdd_sweep
+# cell: 3 (vdd [V], temp_k [K]) points x 2 refresh margins
+FULL_VDDS = (VDD_SWEEP_POINT, (0.9, 300.0), (1.1, 358.0))
+FULL_MARGINS = (0.8, 0.5)
 
 # the frozen slice: small but covers every mem type, LS on/off, and both a
 # shallow and a deep array (delay-chain quantization edge)
@@ -236,10 +240,9 @@ def vdd_tasks() -> dict:
 
 
 def full_sweep_tasks() -> dict:
-    """The 7 Table-2 tasks composed over the full sweep of
-    ``benchmarks/vdd_sweep.py`` (3 vdd points x 2 refresh margins): the
-    winner's labels, picks, tiles and power."""
-    from benchmarks.vdd_sweep import FULL_MARGINS, FULL_VDDS
+    """The 7 Table-2 tasks composed over the full sweep (``FULL_VDDS`` x
+    ``FULL_MARGINS``: 3 vdd points x 2 refresh margins): the winner's
+    labels, picks, tiles and power."""
     from repro.core import gainsight
     from repro.hetero import ComposePolicy, compose
 

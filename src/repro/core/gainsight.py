@@ -78,7 +78,7 @@ NLEVEL_REFERENCE = (
 def nlevel_task(n_levels: int = 3) -> TaskReq:
     """The first ``n_levels`` levels of NLEVEL_REFERENCE as a ``TaskReq``
     (1 <= n_levels <= 5) — the standard deep-hierarchy input for N-level
-    composition tests and ``benchmarks/hetero_nlevel.py``."""
+    composition tests and ``chip_smoke.py``."""
     if not 1 <= n_levels <= len(NLEVEL_REFERENCE):
         raise ValueError(f"n_levels must be in [1, {len(NLEVEL_REFERENCE)}], "
                          f"got {n_levels}")

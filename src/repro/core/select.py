@@ -8,9 +8,8 @@ permits"), and the pure-numpy feasibility / Pareto / bucket-selection
 primitives those policies are built from.
 
 It deliberately imports nothing from the rest of ``repro`` so that
-``repro.core.gainsight`` (task tables) and ``repro.core.dse`` (deprecated
-shims) can import the data model without creating a cycle through the
-``repro.api`` façade.
+``repro.core.gainsight`` (task tables) can import the data model without
+creating a cycle through the ``repro.api`` façade.
 """
 from __future__ import annotations
 

@@ -12,7 +12,7 @@ candidate lists, so the lists are kept deliberately small:
     under which the joint path provably reproduces ``select_level``.
 ``all_feasible``
     every feasible row, capped at ``max_per_bucket`` — the mode for
-    exhaustive sweeps and benchmarks. The list (and therefore what the cap
+    exhaustive sweeps and the benchmark. The list (and therefore what the cap
     and any grid trimming keep) is ordered by the active objective:
     preference-rank-major by default; for "power"/"area"/"balanced" it is
     ordered by the row's **tiled slot contribution** — the quantity the

@@ -9,7 +9,7 @@ For each cell this produces artifacts/dryrun/<arch>__<shape>__<mesh>.json with
   - per-device cost analysis (HLO flops, bytes accessed)
   - collective traffic parsed from the partitioned HLO (per collective kind)
   - MODEL_FLOPS (6·N·D or 2·N·D with N_active for MoE)
-which benchmarks/roofline_table.py turns into the three roofline terms.
+which profiler/traffic.py turns into per-cell memory requirements.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k [--multi-pod]

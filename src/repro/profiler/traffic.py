@@ -28,7 +28,7 @@ from repro.core.select import Bucket, LevelReq, TaskReq
 _KIND_TO_PHASE = {"train": "train_step", "prefill": "prefill",
                   "decode": "decode"}
 
-# TPU-v5e-like hardware constants (same as the roofline)
+# TPU-v5e-like hardware constants (peak bf16 FLOP/s, HBM and link bandwidth)
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 LINK_BW = 50e9

@@ -1,7 +1,6 @@
 """The repro.api façade: Compiler/Macro, DesignTable queries + caching,
-explore() -> DSEReport, and consistency with the legacy dse free functions."""
-import warnings
-
+explore() -> DSEReport, and consistency with the repro.core.select
+primitives they are built from."""
 import numpy as np
 import pytest
 
@@ -85,14 +84,17 @@ def test_table_save_load_explicit(tmp_path):
 
 
 def test_feasible_pareto_chain_matches_legacy():
-    from repro.core import dse
+    """The feasible -> pareto chain selects what ``select.feasible_mask`` and
+    ``select.pareto_mask`` select over ``characterize_batch``'s metrics."""
+    import jax.numpy as jnp
+
+    from repro.core import select
+    from repro.core.characterize import characterize_batch
     cfgs = small_space()
     table = DesignTable.from_configs(cfgs)
     f_hz, lt = 1.0e9, 1e-5
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        res = dse.evaluate_space(cfgs)
-        mask = dse.feasible_mask(res, f_hz, lt)
+    res = characterize_batch(jnp.stack([c.to_vector() for c in cfgs]))
+    mask = select.feasible_mask(res, f_hz, lt)
     chain = table.feasible(f_hz, lt)
     assert len(chain) == int(mask.sum())
     assert chain.to_configs() == [c for c, m in zip(cfgs, mask) if m]
@@ -101,9 +103,7 @@ def test_feasible_pareto_chain_matches_legacy():
                               chain["p_leak_w"] + chain["p_refresh_w"])
     pts = np.stack([chain["area_um2"], chain["p_static_w"],
                     chain["t_read_s"]], axis=1)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy_front = dse.pareto_front(pts)
+    legacy_front = select.pareto_mask(pts)
     front = chain.pareto("area_um2", "p_static_w", "t_read_s")
     assert len(front) == int(legacy_front.sum())
     assert front.to_configs() == [c for c, m in zip(chain.to_configs(),
@@ -331,19 +331,17 @@ def test_explore_policy_preference():
 
 
 def test_legacy_select_level_matches_explore():
-    from repro.core import dse
-    cfgs = api.design_space()
-    table = DesignTable.from_configs(cfgs)
+    """explore's L1 labels and picks are ``select.select_level``'s over the
+    same table's metrics and families."""
+    from repro.core import select
+    table = DesignTable.from_configs(api.design_space())
     report = explore(space=table, tasks=gainsight.TASKS)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        res = dse.evaluate_space(cfgs)
-        for t in gainsight.TASKS:
-            l1, picks = dse.select_level(cfgs, res, t.l1)
-            assert l1 == report.selections[t.task_id]["L1"].label
-            new_picks = report.selections[t.task_id]["L1"].picks
-            assert [p["config_idx"] for p in picks] == \
-                [p.config_idx for p in new_picks]
+    for t in gainsight.TASKS:
+        sel = select.select_level(table.metrics, table.families, t.l1)
+        assert sel.label == report.selections[t.task_id]["L1"].label
+        new_picks = report.selections[t.task_id]["L1"].picks
+        assert [p.config_idx for p in sel.picks] == \
+            [p.config_idx for p in new_picks]
 
 
 # ---------------------------------------------------------------- gainsight

@@ -7,7 +7,8 @@ import pytest
 # hypothesis is a dev extra: property tests skip where absent, unit tests run
 from _hypothesis_compat import given, settings, st
 
-from repro.core import bitcells, devices, dse, gainsight, retention, tech
+from repro.api import DesignTable, design_space, gradient_size_macro
+from repro.core import bitcells, devices, gainsight, retention, select, tech
 from repro.core.artifacts import emit_lef, emit_lib, emit_verilog, generate_all
 from repro.core.characterize import characterize_config
 from repro.core.macro import MacroConfig
@@ -123,21 +124,19 @@ def test_aspect_ratio_frequency_cliff():
 
 # -------------------------------------------------------------------------- DSE
 def test_table2_reproduced_exactly():
-    configs = dse.design_space()
-    res = dse.evaluate_space(configs)
+    table = DesignTable.from_configs(design_space())
     for t in gainsight.TASKS:
-        l1, _ = dse.select_level(configs, res, t.l1)
-        l2, _ = dse.select_level(configs, res, t.l2)
+        l1 = select.select_level(table.metrics, table.families, t.l1).label
+        l2 = select.select_level(table.metrics, table.families, t.l2).label
         exp = gainsight.TABLE2_EXPECTED[t.task_id]
         assert l1 == exp["L1"], f"task {t.task_id} L1 {l1} != {exp['L1']}"
         assert l2 == exp["L2"], f"task {t.task_id} L2 {l2} != {exp['L2']}"
 
 
 def test_feasibility_antitone_in_requirements():
-    configs = dse.design_space()
-    res = dse.evaluate_space(configs)
-    easy = dse.feasible_mask(res, 0.2e9, 1e-6)
-    hard = dse.feasible_mask(res, 2.0e9, 1e-3)
+    res = DesignTable.from_configs(design_space()).metrics
+    easy = select.feasible_mask(res, 0.2e9, 1e-6)
+    hard = select.feasible_mask(res, 2.0e9, 1e-3)
     assert easy.sum() >= hard.sum()
     assert np.all(easy | ~hard)                     # hard ⊆ easy
 
@@ -146,7 +145,7 @@ def test_feasibility_antitone_in_requirements():
 def test_pareto_front_correct(seed):
     rng = np.random.default_rng(seed)
     pts = rng.random((40, 3))
-    mask = dse.pareto_front(pts)
+    mask = select.pareto_mask(pts)
     for i in range(len(pts)):
         dominated = any(np.all(pts[j] <= pts[i]) and np.any(pts[j] < pts[i])
                         for j in range(len(pts)) if j != i)
@@ -154,8 +153,8 @@ def test_pareto_front_correct(seed):
 
 
 def test_gradient_sizing_improves_cell_delay():
-    out = dse.gradient_size_macro(MacroConfig(mem_type="gc_sisi",
-                                              word_size=64, num_words=128))
+    out = gradient_size_macro(MacroConfig(mem_type="gc_sisi", word_size=64,
+                                          num_words=128))
     assert out["speedup"] > 1.0
 
 

@@ -316,6 +316,20 @@ def test_bb_prunes_4level_space_10x_with_identical_best(table):
     assert bb.best.metrics["p_w"] == ex.best.metrics["p_w"]
 
 
+def test_4level_power_space_and_winner_are_pinned(table):
+    """The 4-level reference under the power objective has a fixed search
+    space and a fixed winner, not only the same winner in both engines."""
+    task = nlevel_task(4)
+    rep = compose(table, task, compose_policy=ComposePolicy(
+        search="branch_and_bound", objective="power",
+        candidate_mode="all_feasible", max_candidates_per_bucket=16))
+    assert task.task_id == "nlevel4"
+    assert sum(len(lv.buckets) for lv in task.levels.values()) == 5
+    assert rep.n_space == 917_504
+    assert rep.labels() == {"RF": "SRAM", "L1": "SRAM",
+                            "L2": "OS-Si GCRAM + SRAM", "SPM": "OS-Si GCRAM"}
+
+
 def test_auto_search_switches_on_space_size(table):
     task = nlevel_task(4)
     big = compose(table, task, compose_policy=ComposePolicy(

@@ -1,5 +1,5 @@
-"""repro.obs: tracer / metrics / export / report contracts, the hot-path
-instrumentation, and the benchmark perf-compare.
+"""repro.obs: tracer / metrics / export / report contracts and the hot-path
+instrumentation.
 
 The two non-negotiable guarantees proven here:
 
@@ -21,7 +21,6 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from benchmarks import compare
 from repro import obs
 from repro.api import (Compiler, DesignTable, characterize_call_count,
                        design_space)
@@ -552,73 +551,3 @@ def test_catalog_covers_every_emitted_name(table):
             if name.startswith("t."):          # fixtures from this file
                 continue
             assert catalog.covers(name), name
-
-
-# ------------------------------------------------------- bench perf-compare
-def test_compare_flatten_and_classify():
-    base = {"bench": "x", "quick": True, "table2_matches": 7,
-            "sweep": {"latency_s": 1.0, "rows_per_s": 100.0},
-            "best_labels": {"L1": "SRAM"}, "n_extra": 5}
-    # identical -> ok everywhere, env keys never judged
-    d = compare.diff_records(base, dict(base))
-    assert d["ok"] and not d["regressions"]
-    assert d["metrics"]["bench"]["status"] == "env"
-    assert d["metrics"]["sweep.rows_per_s"]["status"] == "ok"
-    # parity drift is a regression regardless of magnitude
-    cur = json.loads(json.dumps(base))
-    cur["table2_matches"] = 6
-    d = compare.diff_records(base, cur)
-    assert d["regressions"] == ["table2_matches"] and not d["ok"]
-    # label maps stay atomic and exact
-    cur = json.loads(json.dumps(base))
-    cur["best_labels"] = {"L1": "OS-Si GCRAM"}
-    assert compare.diff_records(base, cur)["regressions"] == ["best_labels"]
-    # throughput: 3x slower is a regression, 3x faster an improvement
-    cur = json.loads(json.dumps(base))
-    cur["sweep"]["rows_per_s"] = 30.0
-    d = compare.diff_records(base, cur)
-    assert d["metrics"]["sweep.rows_per_s"]["status"] == "regression"
-    cur["sweep"]["rows_per_s"] = 300.0
-    d = compare.diff_records(base, cur)
-    assert d["metrics"]["sweep.rows_per_s"]["status"] == "improved"
-    # latency inverts the rule; inside the band is ok
-    cur = json.loads(json.dumps(base))
-    cur["sweep"]["latency_s"] = 3.0
-    assert compare.diff_records(base, cur)["metrics"][
-        "sweep.latency_s"]["status"] == "regression"
-    cur["sweep"]["latency_s"] = 1.5
-    assert compare.diff_records(base, cur)["metrics"][
-        "sweep.latency_s"]["status"] == "ok"
-    # non-keyed numeric drift is informational
-    cur = json.loads(json.dumps(base))
-    cur["n_extra"] = 6
-    d = compare.diff_records(base, cur)
-    assert d["metrics"]["n_extra"]["status"] == "changed" and d["ok"]
-
-
-def test_compare_suite_and_missing_files(tmp_path):
-    bdir, cdir = tmp_path / "base", tmp_path / "cur"
-    bdir.mkdir(), cdir.mkdir()
-    rec = {"bench": "b", "table2_matches": 7, "rows_per_s": 10.0}
-    (bdir / "BENCH_a.json").write_text(json.dumps(rec))
-    (cdir / "BENCH_a.json").write_text(json.dumps(rec))
-    (bdir / "BENCH_gone.json").write_text(json.dumps(rec))
-    (cdir / "BENCH_diff.json").write_text("{}")     # never treated as a bench
-    diff = compare.diff_suite(bdir, cdir)
-    assert set(diff["benches"]) == {"BENCH_a.json", "BENCH_gone.json"}
-    assert diff["benches"]["BENCH_a.json"]["ok"]
-    assert diff["benches"]["BENCH_gone.json"]["status"] == "missing"
-    assert diff["ok"]                               # missing != regression
-    assert "BENCH_a.json" in compare.summarize(diff)
-
-
-def test_committed_baselines_match_suite_manifest():
-    """The committed baseline set is exactly the emitted BENCH file set
-    documented in benchmarks/run.py (the drift this PR closes)."""
-    from benchmarks.run import SUITE
-
-    baselines = sorted(
-        p.name for p in
-        (Path(__file__).resolve().parents[1] / "benchmarks"
-         / "baselines").glob("BENCH_*.json"))
-    assert baselines == sorted(fname for _, _, fname in SUITE)

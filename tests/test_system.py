@@ -92,16 +92,18 @@ def test_profiler_to_dse_loop():
     import pathlib
     if not pathlib.Path("artifacts/dryrun").exists():
         pytest.skip("dry-run artifacts not generated")
-    from repro.core import dse
+    from repro.api import DesignTable, design_space
+    from repro.core import select
     from repro.profiler.traffic import arch_requirements, load_dryrun_record
     rec = load_dryrun_record("qwen3-8b", "decode_32k")
     if rec is None:
         pytest.skip("qwen3-8b decode record missing")
     reqs = arch_requirements("qwen3-8b", "decode_32k", rec)
-    configs = dse.design_space()
-    res = dse.evaluate_space(configs)
-    label_l1, picks = dse.select_level(configs, res, reqs["L1"])
-    label_l2, _ = dse.select_level(configs, res, reqs["L2"])
+    table = DesignTable.from_configs(design_space())
+    label_l1 = select.select_level(table.metrics, table.families,
+                                   reqs["L1"]).label
+    label_l2 = select.select_level(table.metrics, table.families,
+                                   reqs["L2"]).label
     assert label_l1 != "infeasible"
     assert label_l2 != "infeasible"
     # L1-analog buffers are core-clock latency-critical -> never OS-Si

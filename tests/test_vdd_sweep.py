@@ -20,9 +20,10 @@ REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO / "scripts"))
 
 from _hypothesis_compat import given, settings, st  # noqa: E402
-from update_golden import (VDD_PATH, VDD_SWEEP_POINT,  # noqa: E402
-                           compose_vdd, full_sweep_tasks, golden_diff,
-                           vdd_tasks, write_vdd_snapshot)
+from update_golden import (FULL_MARGINS, FULL_VDDS,  # noqa: E402
+                           VDD_PATH, VDD_SWEEP_POINT, compose_vdd,
+                           full_sweep_tasks, golden_diff, vdd_tasks,
+                           write_vdd_snapshot)
 
 from repro.api import DesignTable, design_space  # noqa: E402
 from repro.core import bitcells, corners, gainsight, retention  # noqa: E402
@@ -64,6 +65,16 @@ def test_full_sweep_winners_match_golden(vdd_golden):
     the golden contract."""
     drift, _ = golden_diff(vdd_golden["full_sweep"], full_sweep_tasks())
     assert not drift, drift[:20]
+
+
+def test_full_sweep_is_the_chip_cells_sweep():
+    """The golden's full sweep is the very sweep the chip benchmark's
+    ``table2_vdd_sweep`` cell composes over."""
+    traffic = json.loads(
+        (REPO / "bench" / "traffic" / "table2_vdd_sweep.json").read_text())
+    policy = traffic["policy"]
+    assert [tuple(p) for p in policy["vdd_sweep"]] == list(FULL_VDDS)
+    assert tuple(policy["refresh_margin_sweep"]) == FULL_MARGINS
 
 
 def test_base_table2_parity_survives_the_sweep_machinery():
@@ -111,8 +122,7 @@ def test_expand_one_fetch_is_bit_identical_to_per_column(table):
 
     from repro.core import characterize as chz
     from repro.core.macro import encode_axes
-    cp = ComposePolicy(vdd_sweep=((1.2, 233.0), (0.9, 300.0), (1.1, 358.0)),
-                       refresh_margin_sweep=(0.8, 0.5))
+    cp = ComposePolicy(vdd_sweep=FULL_VDDS, refresh_margin_sweep=FULL_MARGINS)
     points = expand.expansion_points(cp)
     assert len(points) == 12
     metrics, _ = expand.expand_metrics(table, table.metrics, points)
